@@ -1,78 +1,14 @@
-"""JAX version compatibility shims.
+"""JAX names the engines reach through one module.
 
-The engines are written against the current public names; older JAX
-releases (this container ships 0.4.37) spell several of them differently.
-Every version-sensitive lookup lives HERE, resolved once at import, so an
-API rename is a one-line fix instead of a grep across engines:
-
-  shard_map       jax.shard_map (new) / jax.experimental.shard_map (old,
-                  where the replication check is spelled `check_rep`;
-                  SAME polarity as the new `check_vma` — True enables
-                  the check on both APIs, so the shim passes the value
-                  through unchanged)
-  enable_x64      jax.enable_x64 (new) / jax.experimental.enable_x64
-  Pallas TPU      pltpu.MemorySpace.{HBM,VMEM} (new) /
-                  pltpu.TPUMemorySpace.{ANY,VMEM} (old — ANY means
-                  "compiler-chosen, HBM-resident for large buffers")
-                  and CompilerParams / TPUCompilerParams
+`shard_map` keeps the positional (f, mesh, in_specs, out_specs) call the
+engines use, over the installed `jax.shard_map` (JAX 0.9).
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:  # jax <= 0.4.x
-    def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
 
-if hasattr(jax, "enable_x64"):
-    enable_x64 = jax.enable_x64
-else:  # jax <= 0.4.x
-    from jax.experimental import enable_x64  # noqa: F401
-
-
-def enable_cpu_collectives() -> bool:
-    """Switch the CPU backend's cross-process collectives onto gloo,
-    returning whether the option exists. Must run BEFORE
-    jax.distributed.initialize. jax 0.4.x ships a CPU backend whose
-    default collectives implementation is 'none' — a multi-process
-    global mesh then fails at dispatch with 'Multiprocess computations
-    aren't implemented on the CPU backend' (the tier-1 env-failure of
-    tests/test_multihost.py). Newer releases select gloo automatically
-    and drop the config knob, hence the hasattr guard."""
-    # probe by update, not hasattr: jax.config only materializes option
-    # attributes on first read, so hasattr is False for never-read
-    # options even when the knob exists (measured on 0.4.37)
-    for key, value in (("jax_cpu_collectives_implementation", "gloo"),
-                       ("jax_cpu_enable_gloo_collectives", True)):
-        try:
-            jax.config.update(key, value)
-            return True
-        except (AttributeError, KeyError, ValueError):
-            continue
-    return False
-
-
-def pallas_tpu_names():
-    """(memory-space enum with .HBM/.VMEM attributes, CompilerParams
-    class) for the installed Pallas TPU module."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    params = getattr(pltpu, "CompilerParams", None)
-    if params is None:
-        params = pltpu.TPUCompilerParams
-    spaces = getattr(pltpu, "MemorySpace", None)
-    if spaces is not None and hasattr(spaces, "HBM"):
-        return spaces, params
-
-    class _Spaces:
-        HBM = pltpu.TPUMemorySpace.ANY
-        VMEM = pltpu.TPUMemorySpace.VMEM
-
-    return _Spaces, params
+def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -66,15 +66,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from quest_tpu import compat
 from quest_tpu import precision
 from quest_tpu.ops import fusion as F
 
-_MEMSPACE, _COMPILER_PARAMS = compat.pallas_tpu_names()
 
 LANE_QUBITS = 7
 LANES = 1 << LANE_QUBITS
 SUBLANE_TOP = 2 * LANE_QUBITS  # first qubit above the sublane band
+TILE_QUBITS = LANE_QUBITS + 3  # qubits inside one (8, 128) f32 memory tile
 ROWS_EFF_BITS = 12    # log2 of rows held per block (scattered x inner):
 # (2, 4096, 128) f32 = 4 MiB per block buffer; with Pallas double-buffering
 # and stage temporaries this stays within VMEM_LIMIT_BYTES
@@ -1375,7 +1374,7 @@ def _apply_batchsel_stage(re, im, st: BatchSelStage, gref,
     trajectory channel, applied inside the sweep. `bsel` is the i32
     batch index (the leading grid dimension / the pipelined driver's
     unraveled step quotient)."""
-    g = pl.load(gref, (pl.ds(bsel, 1), slice(None)))   # (1, 8)
+    g = gref[pl.ds(bsel, 1), :]                       # (1, 8)
     v = [g[0, j] for j in range(8)]
     q = st.qubit
     rows = geo.rows_eff
@@ -1625,6 +1624,33 @@ def _step_index(grid, block_shape, batched):
     return idx_of
 
 
+class _BlockDMA:
+    """One block transfer between the HBM state view and a VMEM slot,
+    issued as one DMA per (re, im) plane on a shared semaphore. A single
+    DMA over both planes strides its plane axis by a whole plane, and on
+    the chip that stride wraps at 2^32 bytes: from 30 qubits on (4 GiB
+    planes) such a DMA reads and writes the wrong plane, while per-plane
+    DMAs land right at every offset (measured on a v5e, libtpu 0.0.34,
+    PR 21). `idx` indexes the HBM view with its plane entry at
+    `plane_axis`; the slot holds one block in the same order."""
+
+    def __init__(self, hbm, idx, slot, sem, *, to_hbm, plane_axis):
+        self.copies = []
+        for p in range(2):
+            hbm_p = hbm.at[idx[:plane_axis] + (p,) + idx[plane_axis + 1:]]
+            slot_p = slot.at[(slice(None),) * plane_axis + (p,)]
+            src, dst = (slot_p, hbm_p) if to_hbm else (hbm_p, slot_p)
+            self.copies.append(pltpu.make_async_copy(src, dst, sem))
+
+    def start(self):
+        for c in self.copies:
+            c.start()
+
+    def wait(self):
+        for c in self.copies:
+            c.wait()
+
+
 def _pipelined_kernel(in_hbm, *rest, stages, geo: _Geometry, grid,
                       block_shape, nbuf, nbatch=1, batched=None):
     """LEGACY manually pipelined segment driver (QUEST_FUSED_PIPELINE=0
@@ -1653,16 +1679,19 @@ def _pipelined_kernel(in_hbm, *rest, stages, geo: _Geometry, grid,
     idx_of = _step_index(grid, block_shape, batched)
     slot_shape = (1, *block_shape) if batched else block_shape
 
+    plane_axis = 1 if batched else 0
+
     def body(scratch, in_sems, out_sems):
         def get_in(step, slot):
             idx, _, _ = idx_of(step)
-            return pltpu.make_async_copy(
-                in_hbm.at[idx], scratch.at[slot], in_sems.at[slot])
+            return _BlockDMA(in_hbm, idx, scratch.at[slot], in_sems.at[slot],
+                             to_hbm=False, plane_axis=plane_axis)
 
         def get_out(step, slot):
             idx, _, _ = idx_of(step)
-            return pltpu.make_async_copy(
-                scratch.at[slot], out_hbm.at[idx], out_sems.at[slot])
+            return _BlockDMA(out_hbm, idx, scratch.at[slot],
+                             out_sems.at[slot], to_hbm=True,
+                             plane_axis=plane_axis)
 
         get_in(0, 0).start()
 
@@ -1772,16 +1801,19 @@ def _decoupled_kernel(in_hbm, *rest, stages, geo: _Geometry, grid,
     idx_of = _step_index(grid, block_shape, batched)
     slot_shape = (1, *block_shape) if batched else block_shape
 
+    plane_axis = 1 if batched else 0
+
     def body(in_scr, out_scr, in_sems, out_sems):
         def get_in(step, slot):
             idx, _, _ = idx_of(step)
-            return pltpu.make_async_copy(
-                in_hbm.at[idx], in_scr.at[slot], in_sems.at[slot])
+            return _BlockDMA(in_hbm, idx, in_scr.at[slot], in_sems.at[slot],
+                             to_hbm=False, plane_axis=plane_axis)
 
         def get_out(step, slot):
             idx, _, _ = idx_of(step)
-            return pltpu.make_async_copy(
-                out_scr.at[slot], out_hbm.at[idx], out_sems.at[slot])
+            return _BlockDMA(out_hbm, idx, out_scr.at[slot],
+                             out_sems.at[slot], to_hbm=True,
+                             plane_axis=plane_axis)
 
         for j in range(n_in):                # fill the read ring
             get_in(j, j).start()
@@ -1979,17 +2011,17 @@ def compile_segment(stages: Sequence, n: int,
                 nbatch=nbatch, batched=batched)
         # the state stays in HBM; the kernel DMAs its own blocks through
         # the in-place slot buffers. Operands are whole-array VMEM.
-        in_specs = [pl.BlockSpec(memory_space=_MEMSPACE.HBM)]
+        in_specs = [pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)]
         for _ in stages:
             in_specs.append(
-                pl.BlockSpec(memory_space=_MEMSPACE.VMEM))
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM))
         fn = pl.pallas_call(
             kernel,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(memory_space=_MEMSPACE.HBM),
+            out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             out_shape=jax.ShapeDtypeStruct(full_view, jnp.float32),
             input_output_aliases={0: 0},  # in-place on the state buffer
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
         )
@@ -2028,7 +2060,7 @@ def compile_segment(stages: Sequence, n: int,
             out_specs=pl.BlockSpec(full_block, index_map),
             out_shape=jax.ShapeDtypeStruct(full_view, jnp.float32),
             input_output_aliases={0: 0},  # in-place on the state buffer
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
         )
@@ -2048,7 +2080,7 @@ def compile_segment(stages: Sequence, n: int,
         if interpret:
             out = fn(amps.reshape(full_view), *mat_arrays)
         else:
-            with compat.enable_x64(False):
+            with jax.enable_x64(False):
                 out = fn(amps.reshape(full_view), *mat_arrays)
         if batched:
             return out.reshape(nbatch, 2, -1, LANES)
@@ -2075,4 +2107,4 @@ def compile_segment_cached(cache: dict, stages: Sequence, n: int,
 
 def usable(n: int) -> bool:
     """Need at least one (8, 128) f32 tile per block."""
-    return n >= LANE_QUBITS + 3
+    return n >= TILE_QUBITS

@@ -560,7 +560,8 @@ def coalesce(flat: Sequence, n: int, local_n: int,
             paying += bool(ex)
             pp += ex
         need_local = {t for op in ops_p for t in op.targets}
-        slots = [s for s in range(local_n) if tr.inv[s] not in need_local]
+        slots = R.above_tile_first(
+            [s for s in range(local_n) if tr.inv[s] not in need_local], g)
         D = 1 << g
         a2a_cost = _cost([("a2a", 2 << local_n, None)], D, topo)
         if (paying >= 2 and len(slots) >= g
@@ -764,10 +765,12 @@ def coalesce_clusters(flat: Sequence, n: int, local_n: int,
                 if sw_cost < best_cost:
                     best_cost, mechanism = sw_cost, "swaps"
         if mechanism == "event":
+            free = R.above_tile_first(free, g)
             free.sort(key=lambda s: next_use(tr.inv[s], i), reverse=True)
             tr.emit_relabel(_home_order(
                 free[:g], tr, hot_key=lambda s: next_use(tr.inv[s], i)))
         elif mechanism == "swaps":
+            free = R.above_tile_first(free, len(glob_need))
             for q in glob_need:
                 free.sort(key=lambda s: next_use(tr.inv[s], i),
                           reverse=True)

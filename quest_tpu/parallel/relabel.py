@@ -65,6 +65,18 @@ def reject_dynamic_ops(flat: Sequence, pass_name: str) -> None:
                 "circuits only")
 
 
+def above_tile_first(slots: Sequence[int], k: int) -> List[int]:
+    """Candidate local slots for k qubits moving off the device bits,
+    kept to those above the TPU's (8, 128) f32 memory tile whenever at
+    least k of them qualify. Swapping device bits with those slots moves
+    whole tiles; a lane or sublane slot makes the views around the
+    all-to-all padded tiny-minor-dim relayouts, 64x the chunk in
+    temporaries (PR 21)."""
+    from quest_tpu.ops.pallas_band import TILE_QUBITS
+    above = [s for s in slots if s >= TILE_QUBITS]
+    return above if len(above) >= k else list(slots)
+
+
 class _PermTracker:
     """Logical->physical permutation bookkeeping for the rewrite passes
     that move qubits (plan_full_relabels, comm.coalesce): emits relabel
@@ -121,7 +133,8 @@ class _PermTracker:
         needs_fix = any(inv[local_n + j] != local_n + j for j in range(g))
         owed_at_device = any(perm[local_n + j] >= local_n
                              for j in range(g))
-        safe = [s for s in range(local_n) if inv[s] < local_n]
+        safe = above_tile_first(
+            [s for s in range(local_n) if inv[s] < local_n], g)
         if needs_fix and owed_at_device and len(safe) < g:
             # tiny chunk: not enough safe slots for the two-step
             # restore; fall back to plain swaps (the engine swap-dances
@@ -503,7 +516,8 @@ def plan_full_relabels(flat: Sequence, n: int, local_n: int,
         O(window), not O(circuit), per candidate. Returns fires=False
         when the current targets leave fewer than g evictable slots."""
         cur = set(flat[i].targets)
-        pool = [s for s in range(local_n) if inv[s] not in cur]
+        pool = above_tile_first(
+            [s for s in range(local_n) if inv[s] not in cur], g)
         if len(pool) < g:
             return [], False
         scores = sorted(pool, key=lambda s: next_use(inv[s], i),

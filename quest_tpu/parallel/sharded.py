@@ -418,13 +418,17 @@ def _relabel_op(chunk, *, local_n, slots):
     parallel.relabel.plan_full_relabels; validated bit-exactly against
     a host bit-swap oracle (tests/test_lazy_relabel.py)."""
     g = len(slots)
-    planes = chunk.reshape((2,) + (2,) * local_n)  # plane, b_{ln-1}..b_0
-    axes_front = [1 + (local_n - 1 - q) for q in reversed(slots)]
-    rest = [a for a in range(1, local_n + 1) if a not in axes_front]
+    # only the slot bits get their own axes; the bits between them stay
+    # merged segments: a rank-(local_n + 1) view of size-2 axes pads
+    # every tiny minor tile to the TPU's (8, 128), which at 22q on four
+    # chips held 1 GiB of temporaries for an 8 MiB shard (PR 21)
+    dims, axis_of = A.seg_view(local_n, tuple(sorted(slots, reverse=True)))
+    axes_front = [1 + axis_of[q] for q in reversed(slots)]
+    rest = [a for a in range(1, len(dims) + 1) if a not in axes_front]
     perm = [0] + axes_front + rest
-    x = planes.transpose(perm).reshape(2, 1 << g, -1)
+    x = chunk.reshape((2,) + dims).transpose(perm).reshape(2, 1 << g, -1)
     y = lax.all_to_all(x, AMP_AXIS, split_axis=1, concat_axis=1)
-    y = y.reshape((2,) + (2,) * local_n)
+    y = y.reshape((2,) + tuple(dims[a - 1] for a in perm[1:]))
     inv = np.argsort(perm)
     return y.transpose(list(inv)).reshape(2, -1)
 

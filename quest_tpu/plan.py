@@ -678,18 +678,14 @@ def _self_digest(meta: dict) -> str:
 def plan_cache_dir(create: bool = True) -> Optional[str]:
     """The plan cache directory: QUEST_PLAN_CACHE_DIR, defaulting to
     `<compile cache>.plans` — literally next to the XLA compile cache
-    (precision.enable_compile_cache), so the two warm-restart stores
+    (precision.compile_cache_dir), so the two warm-restart stores
     travel together. None when the location is unwritable (callers
     fall back to searching, loudly counted)."""
     from quest_tpu.env import knob_value
+    from quest_tpu.precision import compile_cache_dir
     path = knob_value("QUEST_PLAN_CACHE_DIR")
     if path is None:
-        base = knob_value("QUEST_COMPILE_CACHE_DIR")
-        if base is None:
-            repo = os.path.dirname(os.path.dirname(os.path.abspath(
-                __file__)))
-            base = os.path.join(repo, ".jax_cache")
-        path = base + ".plans"
+        path = compile_cache_dir().rstrip(os.sep) + ".plans"
     if create:
         try:
             os.makedirs(path, exist_ok=True)

@@ -138,45 +138,35 @@ def _install_cache_listener() -> None:
     _cache_listener_installed = True
 
 
+def compile_cache_dir() -> str:
+    """Where the persistent compile cache lives: JAX's own
+    JAX_COMPILATION_CACHE_DIR when the environment sets it, otherwise
+    `.jax_cache` under the repo — one fixed path, since the path is part
+    of what the cache is keyed on."""
+    import os
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(repo, ".jax_cache")
+
+
 def enable_compile_cache(path: str = None,
                          min_compile_secs: float = 1.0) -> None:
     """Turn on JAX's persistent compile cache (one shared location for the
     test suite, bench, probes and the driver entry points — circuit
-    programs are compile-dominated on first run). The default location
-    is `.jax_cache` under the repo so the cache survives /tmp cleanups
-    and rides along with checkouts; override with `path` or the
-    QUEST_COMPILE_CACHE_DIR knob (docs/CONFIG.md). Hits/misses tally
-    into the `compile_cache_hits`/`compile_cache_misses` counters of
-    `quest_tpu.serve.metrics` (programmatically readable via
+    programs are compile-dominated on first run). A directory set from
+    outside through JAX_COMPILATION_CACHE_DIR wins over `path`;
+    otherwise `path`, defaulting to compile_cache_dir(). Hits/misses
+    tally into the `compile_cache_hits`/`compile_cache_misses` counters
+    of `quest_tpu.serve.metrics` (programmatically readable via
     `metrics.snapshot()`) and are logged on stderr, derived from those
     counters (_install_cache_listener)."""
     import os
 
     import jax
-    if path is None:
-        from quest_tpu.env import knob_value
-        path = knob_value("QUEST_COMPILE_CACHE_DIR")
-        if path is None:
-            repo = os.path.dirname(os.path.dirname(os.path.abspath(
-                __file__)))
-            path = os.path.join(repo, ".jax_cache")
-            # the repo default only makes sense for checkout use; an
-            # INSTALLED package would resolve into site-packages —
-            # fall back to the old always-writable /tmp location
-            # rather than silently losing persistence (or polluting
-            # site-packages)
-            try:
-                os.makedirs(path, exist_ok=True)
-                writable = os.access(path, os.W_OK)
-            except OSError:
-                writable = False
-            if not writable:
-                import sys
-                import tempfile
-                path = os.path.join(tempfile.gettempdir(),
-                                    "jax_cache_quest_tpu")
-                print(f"[quest_tpu] repo cache dir not writable; "
-                      f"compile cache at {path}", file=sys.stderr)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") or path is None:
+        path = compile_cache_dir()
     _CACHE_STATS["dir"] = path
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",

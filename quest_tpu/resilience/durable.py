@@ -934,13 +934,7 @@ def run_durable(circuit, state: Qureg, directory: str, *,
             # drain the async step queue BEFORE the checkpoint timer:
             # the first sync point would otherwise absorb the pending
             # steps' compute into the measured checkpoint cost
-            if gang:
-                # sync_array's tiny host slice is not addressable on
-                # every host of a multi-controller mesh
-                jax.block_until_ready(amps)
-            else:
-                from quest_tpu.env import sync_array
-                sync_array(amps)
+            jax.block_until_ready(amps)
             t0 = _time.perf_counter()
             if integrity:
                 _check_integrity(_sentinel_values(amps, info), baseline,

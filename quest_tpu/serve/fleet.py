@@ -160,6 +160,16 @@ class ServeFleet:
         if process is None:
             process = knob_value("QUEST_FLEET_PROC")
         self.process = bool(process)
+        if self.process:
+            import jax
+            if jax.default_backend() == "tpu":
+                # workers inherit this environment, and this process
+                # already holds the chip: each worker would fail or hang
+                # on libtpu's lock (ROADMAP R1 gives each its own chip)
+                raise RuntimeError(
+                    "ServeFleet(process=True) cannot run on a TPU backend: "
+                    "this process holds the chip, so its worker processes "
+                    "cannot load it. Use thread replicas (process=False).")
         if tenant_quota is None:
             tenant_quota = knob_value("QUEST_SERVE_TENANT_QUOTA")
         if isinstance(tenant_quota, int):

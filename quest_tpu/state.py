@@ -125,14 +125,25 @@ def fused_state_shape(n: int):
     return (2, 1 << (n - LANE_QUBITS), LANES)
 
 
+@partial(jax.jit, static_argnames=("n", "rdt", "sharding"))
+def _zero_planes_sharded(*, n, rdt, sharding):
+    """|0...0> planes built in place on `sharding`: each device writes
+    only its own shard (building the whole register first and then
+    device_put-ing it would hold all of it on one device — 32 GiB at
+    32 qubits)."""
+    return jax.lax.with_sharding_constraint(
+        _basis_planes(0, n=n, rdt=rdt), sharding)
+
+
 def _make(num_qubits: int, is_density: bool, dtype, sharding=None) -> Qureg:
     validation.validate_num_qubits(num_qubits)
     dtype = np.dtype(dtype) if dtype is not None else precision.get_default_dtype()
     n = 2 * num_qubits if is_density else num_qubits
     rdt = precision.real_dtype_of(dtype)
-    amps = _basis_planes(0, n=n, rdt=rdt)
-    if sharding is not None:
-        amps = jax.device_put(amps, sharding)
+    if sharding is None:
+        amps = _basis_planes(0, n=n, rdt=rdt)
+    else:
+        amps = _zero_planes_sharded(n=n, rdt=rdt, sharding=sharding)
     return Qureg(amps=amps, num_qubits=num_qubits, is_density=is_density)
 
 

@@ -38,8 +38,7 @@ Usage:
                                           # interpret-mode kernels,
                                           # exercises every experiment
 The report prints as one `[ab-silicon] {...}` JSON line (and pretty
-JSON to stdout), keyed by experiment — ready to paste into the
-round's benchmarks/measured_tpu.json notes.
+JSON to stdout), keyed by experiment.
 """
 
 import json
@@ -70,8 +69,7 @@ def out(**kw):
 
 
 def sync(x):
-    from quest_tpu.env import sync_array
-    sync_array(x)
+    jax.block_until_ready(x)
 
 
 if mode == "bench":

@@ -1,8 +1,8 @@
-"""Pin the relay first-execution cost: per-KERNEL or per-BYTE?
+"""Pin the first-execution cost: per-KERNEL or per-BYTE?
 
 Round-3 measured a fresh process paying 51-266 s before its first step
-completes even with a fully warm XLA cache (measured_tpu.json
-compile_latency note). VERDICT r3 item 5 asks whether shrinking the
+completes even with a fully warm XLA cache, through the remote backend
+of that time. VERDICT r3 item 5 asks whether shrinking the
 distinct Mosaic-kernel count would cut it, or whether the cost tracks
 program SIZE. The existing numbers already hint per-byte (QFT-30: only
 8 distinct kernels, 266 s; bench: few kernels, small program, 8-14 s);

@@ -48,10 +48,9 @@ if mode != "alias":
         if mode == "noalias":
             kw.pop("input_output_aliases", None)
         elif mode == "parallel":
-            from quest_tpu import compat
+            from jax.experimental.pallas import tpu as pltpu
             grid = kw.get("grid")
-            _, params_cls = compat.pallas_tpu_names()
-            kw["compiler_params"] = params_cls(
+            kw["compiler_params"] = pltpu.CompilerParams(
                 vmem_limit_bytes=PB.VMEM_LIMIT_BYTES,
                 dimension_semantics=("parallel",) * len(grid))
         return real_call(kernel, **kw)

@@ -86,12 +86,11 @@ else:  # bench step
     shape = fused_state_shape(n)
     s = basis_planes(0, n=n, rdt=jnp.float32, shape=shape)
     s = step(s)
-    from quest_tpu.env import sync_array
-    sync_array(s)
+    jax.block_until_ready(s)
     t0 = time.perf_counter()
     for _ in range(reps):
         s = step(s)
-    sync_array(s)
+    jax.block_until_ready(s)
     dt = (time.perf_counter() - t0) / reps
     gps = 16 * iters / dt
     out(mode=mode, n=n,

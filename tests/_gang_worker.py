@@ -27,11 +27,6 @@ import jax
 
 jax.config.update("jax_platforms", "cpu")
 
-from quest_tpu.compat import enable_cpu_collectives  # noqa: E402
-
-if not enable_cpu_collectives():
-    print("SKIP: no CPU gloo collectives in this jaxlib", flush=True)
-    sys.exit(0)
 
 PROC = int(sys.argv[1])
 NPROC = int(sys.argv[2])

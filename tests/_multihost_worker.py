@@ -17,14 +17,6 @@ import jax
 
 jax.config.update("jax_platforms", "cpu")
 
-# the 0.4.x CPU backend defaults its cross-process collectives to
-# 'none' and refuses multi-process programs at dispatch; gloo must be
-# selected before jax.distributed.initialize (quest_tpu.compat)
-from quest_tpu.compat import enable_cpu_collectives  # noqa: E402
-
-if not enable_cpu_collectives():
-    print("SKIP: no CPU gloo collectives in this jaxlib", flush=True)
-    sys.exit(0)
 
 PROC = int(sys.argv[1])
 NPROC = int(sys.argv[2])

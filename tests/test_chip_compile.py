@@ -1,0 +1,124 @@
+"""Compile main-path programs for a described TPU v5e, without a chip.
+
+Each case lowers and compiles one program of the main path against a
+`v5e:2x2` topology that JAX describes but does not attach, and checks
+what only the chip's compiler can show: the program holds the Pallas
+kernels (`tpu_custom_call`), and its arguments and temporaries fit the
+chip's 15.75 GiB. Mosaic refuses here what interpret mode accepts (the
+round-5 i64 loop bound under x64, unaligned slices, VMEM over-use), so
+these run under the suite's `jax_enable_x64=True`.
+
+The topology is described inside a module fixture, never at import: only
+one process may load libtpu, and the suite's workers each import every
+test file.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+HBM_LIMIT = int(15.75 * 2 ** 30)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prior)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    """(compiled, kernel count) after asserting the TPU program holds a
+    Pallas kernel and fits the chip."""
+    lowered = fn.lower(*args)
+    kernels = lowered.as_text().count("tpu_custom_call")
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert kernels > 0, "no Pallas kernel in the TPU program"
+    assert used <= HBM_LIMIT, f"{used / 2 ** 30:.2f} GiB > 15.75 GiB"
+    return compiled, kernels
+
+
+def test_fused_engine_30q(one_chip):
+    from quest_tpu.circuit import random_circuit
+    from quest_tpu.state import fused_state_shape
+    c = random_circuit(30, 2, seed=7, entangler="cz")
+    fn = c.compiled_fused(30, False, donate=True)
+    state = jax.ShapeDtypeStruct(fused_state_shape(30), jnp.float32,
+                                 sharding=one_chip)
+    compiled, _ = _compile(fn, state)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 8 * 2 ** 30   # donated in place
+
+
+def test_batched_trajectory_kernel_20q(one_chip):
+    from quest_tpu import trajectories as T
+    from quest_tpu.circuit import Circuit
+    c = Circuit(20)
+    c.h(0).h(9).h(19)
+    c.damping(2, 0.3).depolarising(8, 0.2).dephasing(15, 0.25)
+    fn = T._compiled_traj(c, 20, 8, "fused", False)
+    keys = jax.random.split(jax.random.key(0), 8)
+    spec = jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip)
+    compiled, _ = _compile(fn, spec)
+    bucket_bytes = 8 * 2 * 4 * (1 << 20)
+    # the damping draw's reduced density once padded the batch axis 16x
+    # (2.1 GiB of temporaries for this 64 MiB bucket)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * bucket_bytes
+
+
+def test_density_15q(one_chip):
+    from quest_tpu.circuit import Circuit
+    from quest_tpu.state import fused_state_shape
+    c = Circuit(15)
+    c.damping(1, 0.1).dephasing(7, 0.2).cz(3, 12)
+    fn = c.compiled_fused(30, True, donate=True)
+    state = jax.ShapeDtypeStruct(fused_state_shape(30), jnp.float32,
+                                 sharding=one_chip)
+    _compile(fn, state)
+
+
+def test_sharded_fused_four_chips(topo):
+    """A random circuit crossing the global qubits on a 4-chip mesh:
+    kernels per shard, the relabel all-to-alls between them, and no
+    temporaries from padded tiny-minor-dim views (the compile that
+    exhausted a 62 GB host before the relabel repair)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from quest_tpu.circuit import random_circuit
+    from quest_tpu.env import AMP_AXIS
+    from quest_tpu.parallel.sharded import compile_circuit_sharded_fused
+    n = 24
+    mesh = Mesh(np.array(topo.devices[:4]), (AMP_AXIS,))
+    c = random_circuit(n, 2, seed=11, entangler="cz")
+    fn = compile_circuit_sharded_fused(tuple(c.ops), n, False, mesh,
+                                       donate=True)
+    state = jax.ShapeDtypeStruct((2, 1 << n), jnp.float32,
+                                 sharding=NamedSharding(mesh,
+                                                        P(None, AMP_AXIS)))
+    compiled, _ = _compile(fn, state)
+    hlo = compiled.as_text()
+    assert "all-to-all" in hlo or "collective-permute" in hlo
+    shard_bytes = 2 * 4 * (1 << n) // 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * shard_bytes

@@ -239,8 +239,12 @@ def test_exchange_slicing_structure_and_bit_identity(mesh, monkeypatch):
     """QUEST_EXCHANGE_SLICES=4 must multiply the collective-permute
     count by the slice factor at UNCHANGED total bytes (the overlap
     structure, verifiable on the CPU mesh), keep predicted == lowered,
-    and reproduce the unsliced amplitudes BIT-IDENTICALLY (slicing only
-    splits the transfer; the arithmetic per element is the same)."""
+    and reproduce the unsliced amplitudes (slicing only splits the
+    transfer; the arithmetic per element is the same). Compared to
+    4 eps of the largest amplitude, not bit for bit: XLA:CPU's LLVM
+    backend contracts the combine into FMAs differently per fusion
+    shape — 1 ulp on 3 of 128 f64 values under JAX 0.9, and bit-equal
+    with --xla_backend_optimization_level=0."""
     monkeypatch.setenv("QUEST_COMM_PLAN", "0")   # fixed plain schedule
     c = Circuit(N).rx(N - 1, 0.4).swap(0, N - 1)
     n = N
@@ -260,7 +264,9 @@ def test_exchange_slicing_structure_and_bit_identity(mesh, monkeypatch):
     monkeypatch.setenv("QUEST_EXCHANGE_SLICES", "4")
     f4 = S.compile_circuit_sharded(c.ops, n, False, mesh, donate=False)
     b = np.asarray(f4(sq.amps))
-    assert np.array_equal(a, b), "slicing changed the arithmetic"
+    np.testing.assert_allclose(b, a, rtol=0,
+                               atol=4 * np.finfo(a.dtype).eps
+                               * np.abs(a).max())
 
 
 def test_effective_slices_clamps():

@@ -640,8 +640,6 @@ def test_elastic_gang_soak_two_process(tmp_path):
         for p in procs:
             out, _ = p.communicate(timeout=600)
             outs.append(out)
-        if any("SKIP:" in o for o in outs):
-            pytest.skip("jaxlib lacks CPU gloo collectives")
         for i, (p, o) in enumerate(zip(procs, outs)):
             assert p.returncode == 0, f"proc {i} ({phase}):\n{o[-4000:]}"
         return outs

@@ -464,3 +464,15 @@ def test_scale_down_rolls_back_instead_of_losing_requests():
         fleet.drain(timeout_s=300)
         for f in futs:
             f.result(timeout=120)       # nothing was lost
+
+
+def test_process_fleet_refused_on_a_tpu_backend(monkeypatch):
+    """The parent holds the chip, so process replicas that inherit its
+    environment could not load it: construction fails loudly before any
+    worker starts (thread replicas stay the TPU path until ROADMAP R1)."""
+    import jax
+
+    from quest_tpu.serve.fleet import ServeFleet
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        ServeFleet(replicas=1, process=True)

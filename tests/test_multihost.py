@@ -45,10 +45,6 @@ def test_two_process_global_mesh():
         for p in procs:
             if p.poll() is None:
                 p.kill()
-    if any("SKIP:" in out for out in outs):
-        # the worker probed its jaxlib and found no CPU gloo collectives
-        # implementation — the mesh itself is untestable there
-        pytest.skip("jaxlib lacks CPU cross-process (gloo) collectives")
     for i, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"proc {i} failed:\n{out[-3000:]}"
         assert "shards ok" in out, out[-1000:]

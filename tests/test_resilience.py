@@ -532,68 +532,8 @@ def test_sharded_dispatch_site_fires():
 
 
 # ---------------------------------------------------------------------------
-# satellites: env probe retry, native warn-once
+# satellites: native warn-once
 # ---------------------------------------------------------------------------
-
-
-class _Proc:
-    def __init__(self, returncode, stdout="", stderr=""):
-        self.returncode = returncode
-        self.stdout = stdout
-        self.stderr = stderr
-
-
-def test_backend_probe_retries_lock_contention_before_downgrading():
-    """Regression for the env.py probe-retry contract: a fast nonzero
-    exit (another process holding the device's exclusive lock) retries
-    — with the inter-attempt sleep — before giving up; success on a
-    later attempt returns the platform with no downgrade."""
-    from quest_tpu.env import _probe_subprocess
-
-    calls, sleeps = [], []
-    outcomes = [_Proc(1, stderr="device locked by pid 123"),
-                _Proc(1, stderr="device locked by pid 123"),
-                _Proc(0, stdout="tpu\n")]
-
-    def fake_run(cmd, **kw):
-        calls.append(cmd)
-        return outcomes[len(calls) - 1]
-
-    platform, err = _probe_subprocess("code", 30, _run=fake_run,
-                                      _sleep=sleeps.append)
-    assert platform == "tpu" and err == ""
-    assert len(calls) == 3                   # retried twice, then won
-    assert sleeps == [20.0, 20.0]
-
-
-def test_backend_probe_exhausted_retries_report_last_error():
-    from quest_tpu.env import _probe_subprocess
-
-    sleeps = []
-    platform, err = _probe_subprocess(
-        "code", 30, _run=lambda cmd, **kw: _Proc(1, stderr="locked"),
-        _sleep=sleeps.append)
-    assert platform is None and "locked" in err
-    assert len(sleeps) == 2                  # attempts-1 sleeps
-
-
-def test_backend_probe_timeout_downgrades_immediately():
-    """A TIMEOUT is a hung init, not lock contention: no retries (they
-    would triple a 240s wait for nothing)."""
-    import subprocess
-
-    from quest_tpu.env import _probe_subprocess
-
-    calls, sleeps = [], []
-
-    def fake_run(cmd, **kw):
-        calls.append(cmd)
-        raise subprocess.TimeoutExpired(cmd, kw["timeout"])
-
-    platform, err = _probe_subprocess("code", 7, _run=fake_run,
-                                      _sleep=sleeps.append)
-    assert platform is None and "timed out after 7s" in err
-    assert len(calls) == 1 and sleeps == []
 
 
 def test_native_degrade_warns_once_and_keeps_working(monkeypatch, capsys):

@@ -496,6 +496,36 @@ def test_compile_cache_counters_are_structured():
     assert hits.value + misses.value >= before    # tallies move, not logs
 
 
+@pytest.mark.parametrize("outside", [True, False])
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path, outside):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and wins over a
+    caller's path=; unset, the cache is <repo>/.jax_cache. The plan
+    cache sits next to whichever it is."""
+    import os
+
+    import jax
+
+    from quest_tpu import plan, precision
+    prior = jax.config.jax_compilation_cache_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = str(tmp_path / "x") if outside else os.path.join(repo,
+                                                            ".jax_cache")
+    if outside:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("QUEST_PLAN_CACHE_DIR", raising=False)
+    try:
+        precision.enable_compile_cache(
+            path=str(tmp_path / "ignored") if outside else None,
+            min_compile_secs=0.1)
+        assert jax.config.jax_compilation_cache_dir == want
+        assert plan.plan_cache_dir(create=False) == want + ".plans"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prior)
+        precision._CACHE_STATS["dir"] = prior
+
+
 # ---------------------------------------------------------------------------
 # warmup
 # ---------------------------------------------------------------------------

@@ -128,3 +128,17 @@ def test_unitary_mixture_zero_probability_branch_never_drawn():
     keys = jax.random.split(jax.random.key(12), 2000)
     ks = np.asarray(jax.vmap(shot)(keys))
     assert np.all(ks == 0)
+
+
+@pytest.mark.parametrize("q", [0, 6, 7, 9, 11])
+def test_reduced_density_one_qubit_matches_dense(q):
+    """The 1-qubit reduced density (lane and row target bits, a roll and
+    masked sums on the (rows, 128) view) equals the dense partial trace."""
+    n, b = 12, 3
+    rng = np.random.default_rng(q)
+    planes = rng.standard_normal((b, 2, 1 << n)).astype(np.float32)
+    got = np.asarray(T._reduced_density(jnp.asarray(planes), n, (q,)))
+    psi = (planes[:, 0] + 1j * planes[:, 1]).astype(np.complex128)
+    v = psi.reshape(b, 1 << (n - 1 - q), 2, 1 << q)
+    want = np.einsum("bpir,bpjr->bij", v, v.conj())
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * np.abs(want).max())
