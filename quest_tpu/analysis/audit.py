@@ -36,49 +36,31 @@ class StaleCacheError(AssertionError):
 
 
 class CompileAuditor:
-    """Counts jit traces while active, via jax's monitoring events
-    (one '/jax/core/compile/jaxpr_trace_duration' duration event fires
-    per trace; backend compiles are counted separately). Nestable and
-    re-enterable; the process-wide listener is registered on first
-    enter and left installed (jax 0.4.x has no public unregister) —
-    events only reach auditors currently in `_installed`, so exited
-    auditors cost one empty-list iteration."""
-
-    _installed: List["CompileAuditor"] = []
-    _listener_registered = False
+    """Counts jit traces while active, from JAX's compile-phase events
+    through quest_tpu.profiling.on_compile_event (one 'jaxpr_trace'
+    event fires per trace; backend compiles, cache loads included, are
+    counted separately). Nestable and re-enterable."""
 
     def __init__(self):
         self.traces = 0
         self.backend_compiles = 0
 
-    # -- event plumbing ---------------------------------------------------
-    @classmethod
-    def _ensure_listener(cls) -> None:
-        if cls._listener_registered:
-            return
-        from jax._src import monitoring
-
-        def on_duration(event: str, duration: float, **kw) -> None:
-            if event.endswith("jaxpr_trace_duration"):
-                for aud in cls._installed:
-                    aud.traces += 1
-            elif event.endswith("backend_compile_duration"):
-                for aud in cls._installed:
-                    aud.backend_compiles += 1
-
-        monitoring.register_event_duration_secs_listener(on_duration)
-        cls._listener_registered = True
+    def _on_compile(self, name: str, start: float, end: float) -> None:
+        if name == "jaxpr_trace":
+            self.traces += 1
+        elif name == "backend_compile":
+            self.backend_compiles += 1
 
     def __enter__(self) -> "CompileAuditor":
-        type(self)._ensure_listener()
+        from quest_tpu import profiling
         self.traces = 0
         self.backend_compiles = 0
-        type(self)._installed.append(self)
+        profiling.on_compile_event(self._on_compile)
         return self
 
     def __exit__(self, *exc) -> None:
-        with contextlib.suppress(ValueError):
-            type(self)._installed.remove(self)
+        from quest_tpu import profiling
+        profiling.off_compile_event(self._on_compile)
 
     # -- assertions -------------------------------------------------------
     def assert_no_retrace(self, what: str = "golden circuit set") -> None:

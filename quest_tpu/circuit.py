@@ -20,6 +20,7 @@ import numpy as np
 
 from quest_tpu import cplx
 from quest_tpu import precision
+from quest_tpu import profiling
 from quest_tpu.ops import apply as A
 from quest_tpu.ops import matrices as M
 from quest_tpu.state import Qureg
@@ -1267,33 +1268,35 @@ class Circuit:
                _engine_mode_key())
         fn = self._compiled.get(key)
         if fn is not None:
+            profiling.count("quest.fused_cache_hit")
             return fn
         if not PB.usable(n):
             fn = self.compiled_banded(n, density, donate, iters=iters)
             self._compiled[key] = fn
             return fn
 
-        flat = self._planned_flat(n, density)
-        # PB.plan_bands now matches fusion's default 7-wide layout, so the
-        # same plan serves both the kernel segmentation and the f64 XLA
-        # band path
-        items = F.plan(flat, n, bands=PB.plan_bands(n))
-        parts = PB.segment_plan(items, n)
-        # sweep fusion (QUEST_SWEEP_FUSION, keyed — _engine_mode_key
-        # carries it): merge geometry-compatible consecutive segments
-        # into single-launch HBM sweeps, INCLUDING across the unrolled
-        # iterations of this program — a repeated block-resident circuit
-        # (the bench's headline/chain steps) collapses from `iters`
-        # kernel launches per dispatch to ~iters/k, each streaming the
-        # state once (quest_tpu/ops/pallas_band.py sweep_plan,
-        # docs/SWEEPS.md). Unrolling the parts list here replaces
-        # _loop's own unroll for the same iteration range, so program
-        # size is unchanged when nothing merges.
-        unroll = iters if 1 < iters <= _LOOP_UNROLL_MAX else 1
-        if PB.sweep_enabled():
-            parts = PB.sweep_plan(parts * unroll, n)
-        else:
-            unroll = 1
+        with profiling.annotate("quest.plan"):
+            flat = self._planned_flat(n, density)
+            # PB.plan_bands now matches fusion's default 7-wide layout, so
+            # the same plan serves both the kernel segmentation and the
+            # f64 XLA band path
+            items = F.plan(flat, n, bands=PB.plan_bands(n))
+            parts = PB.segment_plan(items, n)
+            # sweep fusion (QUEST_SWEEP_FUSION, keyed — _engine_mode_key
+            # carries it): merge geometry-compatible consecutive segments
+            # into single-launch HBM sweeps, INCLUDING across the unrolled
+            # iterations of this program — a repeated block-resident
+            # circuit (the bench's headline/chain steps) collapses from
+            # `iters` kernel launches per dispatch to ~iters/k, each
+            # streaming the state once (quest_tpu/ops/pallas_band.py
+            # sweep_plan, docs/SWEEPS.md). Unrolling the parts list here
+            # replaces _loop's own unroll for the same iteration range,
+            # so program size is unchanged when nothing merges.
+            unroll = iters if 1 < iters <= _LOOP_UNROLL_MAX else 1
+            if PB.sweep_enabled():
+                parts = PB.sweep_plan(parts * unroll, n)
+            else:
+                unroll = 1
         loop_iters = iters // unroll
         seg_cache = {}  # identical-structure segments share one kernel
 
@@ -1328,8 +1331,11 @@ class Circuit:
             shape = amps.shape
 
             def body(a):
-                for f in appliers:
-                    a = f(a)
+                # each sweep's position in the plan reaches the op
+                # metadata of its kernel (or XLA ops) in the trace
+                for i, f in enumerate(appliers):
+                    with jax.named_scope(f"quest.sweep{i:02d}"):
+                        a = f(a)
                 return a
             out = _loop(body, amps.reshape(2, -1, PB.LANES), loop_iters)
             return out.reshape(shape)

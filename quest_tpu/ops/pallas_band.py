@@ -55,8 +55,10 @@ compiled_fused).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import hashlib
 import os
 from typing import List, Optional, Sequence, Tuple
 
@@ -1789,8 +1791,11 @@ def _decoupled_kernel(in_hbm, *rest, stages, geo: _Geometry, grid,
     The in/out waits sit inside jax.named_scope regions
     ('quest:dma_in_wait' / 'quest:dma_out_wait' / 'quest:stages') so a
     chip profile can attribute residual stall time to the read stream,
-    the write stream or the stage chain directly
-    (profiling.sweep_dma_report is the host-side split)."""
+    the write stream or the stage chain directly. Mosaic emits them at
+    trace level 10: they reach the device trace only from kernels
+    compiled with libtpu's --xla_enable_custom_call_region_trace=true
+    (docs/SWEEPS.md; profiling.sweep_dma_report is the host-side split
+    without it)."""
     mat_refs = rest[:len(stages)]
     out_hbm = rest[len(stages)]
     if batched is None:
@@ -1949,6 +1954,23 @@ def segment_geometry(stages: Sequence, n: int,
     return _geometry(n, scat_bits, rows_eff_bits)
 
 
+def kernel_name(stages: Sequence, geo: _Geometry,
+                batch: int | None = None) -> str:
+    """The stable name a segment's kernel carries into the HLO and the
+    device trace: its stage kinds and counts, its block geometry (inner
+    row bits `r`, scattered axes `s`) and a digest of the whole
+    structure, e.g. `quest_seg_mat2_phase1_r13s1_5f0c2a91`. Segments
+    that share a kernel (compile_segment_cached) share the name; it
+    changes only when what the kernel computes does."""
+    kinds = collections.Counter(
+        type(st).__name__.removesuffix("Stage").lower() for st in stages)
+    parts = "_".join(f"{k}{c}" for k, c in sorted(kinds.items())) or "copy"
+    digest = hashlib.sha1(
+        repr((tuple(stages), geo, batch)).encode()).hexdigest()[:8]
+    return (f"quest_seg_{parts}_r{geo.inner_bits}s{len(geo.scat)}"
+            f"{'' if batch is None else f'_b{batch}'}_{digest}")
+
+
 def compile_segment(stages: Sequence, n: int,
                     rows_eff_bits: int | None = None,
                     interpret: bool = False, batch: int | None = None):
@@ -1969,6 +1991,7 @@ def compile_segment(stages: Sequence, n: int,
     grid_axes = [i for i, b in enumerate(blocks) if b == 1]
     batched = batch is not None
     nbatch = batch if batched else 1
+    name = kernel_name(stages, geo, batch)
 
     def index_map(*ids):
         # batched: the leading grid id selects the state; row-axis
@@ -2024,6 +2047,7 @@ def compile_segment(stages: Sequence, n: int,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
+            name=name,
         )
     else:
         kernel = functools.partial(_segment_kernel, stages=tuple(stages),
@@ -2063,6 +2087,7 @@ def compile_segment(stages: Sequence, n: int,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
+            name=name,
         )
 
     def apply(amps, mat_arrays):

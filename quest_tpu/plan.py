@@ -243,6 +243,12 @@ def build_plan(circuit, *, density: bool = False,
     knobs, unpriced (engine = the incumbent route, no candidate search):
     the cheap path `Circuit.plan_stats()` rides on every call. Use
     `autotune()` for the priced search + persistent cache."""
+    from quest_tpu import profiling
+    with profiling.annotate("quest.plan"):
+        return _build_plan(circuit, density, batch, devices, dtype)
+
+
+def _build_plan(circuit, density, batch, devices, dtype) -> ProgramPlan:
     n = circuit.num_qubits * 2 if density else circuit.num_qubits
     recs = _subsystem_records(circuit, n, density, batch, devices)
     incumbent = _incumbent_engine(len(circuit.ops), devices)

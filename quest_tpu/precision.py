@@ -96,37 +96,36 @@ def _cache_counters():
 
 
 def _install_cache_listener() -> None:
-    """Register a jax monitoring listener that tallies persistent-cache
-    hits/misses into serve.metrics counters and logs them on stderr:
-    every MISS is announced as it happens (a miss is when you pay the
-    compile — the f64-26q warmup is ~297 s on chip), hits are counted
-    and summarized at exit so repeat bench runs show what the cache
-    saved without per-dispatch spam. Left installed for the process
-    lifetime (jax 0.4.x has no public unregister), like
-    analysis.audit.CompileAuditor's listener."""
+    """Tally persistent-cache hits/misses into serve.metrics counters and
+    log them on stderr: every MISS is announced as it happens (a miss is
+    when you pay the compile — the f64-26q warmup is ~297 s on chip),
+    hits are counted and summarized at exit so repeat bench runs show
+    what the cache saved without per-dispatch spam. The events arrive
+    through quest_tpu.profiling's one jax.monitoring hookup, for the
+    process lifetime."""
     global _cache_listener_installed
     if _cache_listener_installed:
         return
     import atexit
     import sys
+
+    from quest_tpu import profiling
     hits, misses = _cache_counters()
 
-    from jax._src import monitoring
-
-    def on_event(event: str, **kw) -> None:
-        if event.endswith("/cache_hits"):
+    def on_event(name: str, start: float, end: float) -> None:
+        if name == "cache_hit":
             hits.inc()
             if hits.value == 1:
                 print(f"[quest_tpu] compile cache HIT "
                       f"({_CACHE_STATS['dir']})", file=sys.stderr,
                       flush=True)
-        elif event.endswith("/cache_misses"):
+        elif name == "cache_miss":
             misses.inc()
             print(f"[quest_tpu] compile cache MISS "
                   f"#{misses.value} (compiling; cached for "
                   f"the next run)", file=sys.stderr, flush=True)
 
-    monitoring.register_event_listener(on_event)
+    profiling.on_compile_event(on_event)
 
     def summary() -> None:
         if hits.value or misses.value:

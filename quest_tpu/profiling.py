@@ -1,4 +1,4 @@
-"""Profiling / tracing hooks.
+"""Profiling and tracing: the program's one span and counter recorder.
 
 The reference has NO profiling support (SURVEY.md §5: the only
 introspection is reportQuregParams/getEnvironmentString). On TPU the
@@ -6,45 +6,238 @@ platform tooling is first-class; this module packages it:
 
   * `trace(dir)` — context manager capturing a profiler trace viewable in
     TensorBoard / Perfetto (wraps jax.profiler).
-  * `annotate(name)` — named region that shows up on the trace timeline.
-  * `op_metrics(fn, *args)` — compile a function and return its XLA cost
-    analysis (flops, bytes accessed) — the quick "is this memory-bound?"
-    check used to tune the engines.
+  * `annotate(name)` — THE span entry point: a named region on the
+    profiler's timeline and, while a `recording()` is active, a
+    (name, parent, start, end) span in that record.
+  * `count(name)` — a program counter, kept by the active recording.
+  * `recording()` — collect spans, counters and JAX's compile phases
+    (tracing, jaxpr->MLIR lowering with the Mosaic kernels, backend
+    compile or cache load) in memory, for the caller to read.
+  * `on_compile_event(fn)` — the one jax.monitoring hookup: recordings,
+    analysis.audit.CompileAuditor and the persistent-cache counters of
+    quest_tpu.precision all listen through it.
+
+With no recording active, `annotate` costs one check of a module global
+beyond the TraceAnnotation, and `count` that check alone.
 """
 
 from __future__ import annotations
 
 import contextlib
+import threading
+import time
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
 
 @contextlib.contextmanager
 def trace(log_dir: str):
-    """Capture a device trace: `with profiling.trace("/tmp/trace"): ...`"""
+    """Capture a device trace: `with profiling.trace("/tmp/trace"): ...`.
+    Under an active recording, its clock anchor is taken first, so the
+    record's spans can be placed on the trace's time base
+    (Recording.spans_on)."""
     jax.profiler.start_trace(log_dir)
     try:
+        if _ACTIVE is not None:
+            _ACTIVE.anchor()
         yield
     finally:
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named trace region: `with profiling.annotate("qft"): ...`"""
-    return jax.profiler.TraceAnnotation(name)
+# ---------------------------------------------------------------------------
+# compile-phase events: the one jax.monitoring registration
+# ---------------------------------------------------------------------------
+
+# JAX's compile-phase duration events, by the counter name a record keeps
+# (jax._src.dispatch: *_EVENT); backend_compile covers a persistent-cache
+# load as well as a compile
+PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "mlir_lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+
+# fn(name, start_s, end_s) on time.time()'s clock; a cache event has
+# start == end
+_compile_listeners: List[Callable[[str, float, float], None]] = []
+_monitoring_installed = False
 
 
-def op_metrics(fn, *args, **kwargs) -> dict:
-    """Lower+compile `fn(*args)` and return XLA's cost analysis
-    (flops / bytes accessed / estimated seconds where available)."""
-    compiled = jax.jit(fn).lower(*args, **kwargs).compile()
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    name = PHASES.get(event)
+    if name is not None:
+        for fn in tuple(_compile_listeners):
+            fn(name, start, end)
+
+
+def _on_event(event: str, **kw) -> None:
+    name = CACHE_EVENTS.get(event)
+    if name is not None:
+        now = time.time()
+        for fn in tuple(_compile_listeners):
+            fn(name, now, now)
+
+
+def on_compile_event(fn: Callable[[str, float, float], None]) -> None:
+    """Call fn(name, start_s, end_s) for every compile-phase event
+    (PHASES) and persistent-cache hit or miss (CACHE_EVENTS). The
+    monitoring listeners are registered once per process and left
+    installed; off_compile_event stops the calls."""
+    global _monitoring_installed
+    if not _monitoring_installed:
+        from jax._src import monitoring
+        monitoring.register_event_time_span_listener(_on_time_span)
+        monitoring.register_event_listener(_on_event)
+        _monitoring_installed = True
+    if fn not in _compile_listeners:
+        _compile_listeners.append(fn)
+
+
+def off_compile_event(fn) -> None:
+    with contextlib.suppress(ValueError):
+        _compile_listeners.remove(fn)
+
+
+# ---------------------------------------------------------------------------
+# the recorder
+# ---------------------------------------------------------------------------
+
+ANCHOR = "quest.clock_anchor"
+
+
+class Span(NamedTuple):
+    name: str
+    parent: Optional[str]     # the enclosing span on the same thread
+    start_ns: int             # time.perf_counter_ns(), or the trace's
+    end_ns: int               # time base after Recording.spans_on
+
+
+def _union_s(intervals) -> float:
+    total, hi = 0.0, float("-inf")
+    for s, e in sorted(intervals):
+        if e > hi:
+            total += e - max(s, hi)
+            hi = e
+    return total
+
+
+class Recording:
+    """Spans, counters and compile phases collected while active.
+
+    `spans` are the annotate() regions that closed; `counts` hold count()
+    and one per compile-phase or cache event; `seconds(phase)` is the
+    wall time covered by a phase's events (a union: a jit traced inside
+    another's trace is not counted twice)."""
+
+    _GUARDED_BY = {"_lock": ("counts", "_phases"),
+                   "<owner-thread>": ("anchor_ns",)}
+
+    def __init__(self):
+        self.spans: List[Span] = []
+        self.counts: Dict[str, int] = {}
+        self._phases: Dict[str, List[Tuple[float, float]]] = {}
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        self.anchor_ns: Optional[int] = None
+
+    def seconds(self, phase: str) -> float:
+        with self._lock:
+            return _union_s(self._phases.get(phase, ()))
+
+    def span_seconds(self, name: str) -> float:
+        return 1e-9 * sum(s.end_ns - s.start_ns for s in self.spans
+                          if s.name == name)
+
+    def _count(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            self.counts[name] = self.counts.get(name, 0) + n
+
+    def _on_compile(self, name: str, start: float, end: float) -> None:
+        with self._lock:
+            self.counts[name] = self.counts.get(name, 0) + 1
+            if end > start:
+                self._phases.setdefault(name, []).append((start, end))
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        stack = self._tls.__dict__.setdefault("stack", [])
+        parent = stack[-1] if stack else None
+        stack.append(name)
+        start = time.perf_counter_ns()
+        try:
+            with jax.profiler.TraceAnnotation(name):
+                yield
+        finally:
+            end = time.perf_counter_ns()
+            stack.pop()
+            self.spans.append(Span(name, parent, start, end))
+
+    def anchor(self) -> None:
+        """Record the clock anchor: an empty span that is also annotated,
+        so that while the profiler runs it lands in both clocks."""
+        with self.span(ANCHOR):
+            pass
+        self.anchor_ns = self.spans[-1].start_ns
+
+    def offset_ns(self, profile) -> int:
+        """Trace time minus record time, from the anchor span's host event
+        in `profile` (a jax.profiler.ProfileData, whose host events count
+        from the profile's start)."""
+        if self.anchor_ns is None:
+            raise ValueError("the recording holds no clock anchor: take "
+                             "one with anchor() while the profiler runs")
+        for plane in profile.planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for e in line.events:
+                        if e.name == ANCHOR:
+                            return int(e.start_ns) - self.anchor_ns
+        raise ValueError(f"the profile holds no {ANCHOR!r} event")
+
+    def spans_on(self, profile) -> List[Span]:
+        """The spans on `profile`'s time base."""
+        off = self.offset_ns(profile)
+        return [s._replace(start_ns=s.start_ns + off, end_ns=s.end_ns + off)
+                for s in self.spans]
+
+
+_ACTIVE: Optional[Recording] = None
+
+
+@contextlib.contextmanager
+def recording():
+    """`with profiling.recording() as rec:` — collect spans, counters and
+    compile phases into `rec` until the block ends. Nothing is written
+    anywhere; read `rec`."""
+    global _ACTIVE
+    rec, prior = Recording(), _ACTIVE
+    on_compile_event(rec._on_compile)
+    _ACTIVE = rec
     try:
-        analysis = compiled.cost_analysis()
-    except Exception:  # backend without cost analysis
-        return {}
-    if isinstance(analysis, list):  # some versions return [dict]
-        analysis = analysis[0] if analysis else {}
-    return dict(analysis)
+        yield rec
+    finally:
+        _ACTIVE = prior
+        off_compile_event(rec._on_compile)
+
+
+def annotate(name: str):
+    """Named trace region: `with profiling.annotate("quest.plan"): ...`,
+    also a span of the active recording."""
+    if _ACTIVE is None:
+        return jax.profiler.TraceAnnotation(name)
+    return _ACTIVE.span(name)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add n to the active recording's counter `name`."""
+    if _ACTIVE is not None:
+        _ACTIVE._count(name, n)
 
 
 # ---------------------------------------------------------------------------
@@ -210,8 +403,10 @@ def sweep_dma_report(n: int = None, reps: int = 5, circuit=None,
     stall lives. The IN-KERNEL attribution rides the named-scope
     labels the decoupled driver wraps its DMA waits in
     ('quest:dma_in_wait' / 'quest:dma_out_wait' / 'quest:stages',
-    pallas_band._decoupled_kernel) — capture with profiling.trace()
-    and the regions land on the chip timeline directly.
+    pallas_band._decoupled_kernel); they reach a device trace only from
+    kernels compiled with libtpu's --xla_enable_custom_call_region_trace
+    (docs/SWEEPS.md), which slows the kernels: this report needs no
+    flag.
 
     Defaults: the bench headline step (bench._build_circuit) unrolled
     `iters` = INNER_STEPS applications, n = 30 on TPU / 12 on a CPU

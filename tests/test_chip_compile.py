@@ -72,6 +72,39 @@ def test_fused_engine_30q(one_chip):
     assert mem.alias_size_in_bytes == 8 * 2 ** 30   # donated in place
 
 
+def test_fused_kernels_carry_names_and_sweep_scopes(one_chip):
+    """Each kernel's custom call is named by its structure
+    (pallas_band.kernel_name) and sits under its sweep's position in the
+    plan (`quest.sweepNN` in op_name), so a device trace can name every
+    kernel event and place it in the plan."""
+    import re
+
+    from quest_tpu.circuit import random_circuit
+    from quest_tpu.state import fused_state_shape
+    n = 22
+    c = random_circuit(n, 2, seed=7, entangler="cz")
+    sweeps = c.plan_stats()["fused"]["hbm_sweeps"]
+    assert sweeps > 1
+    fn = c.compiled_fused(n, False, donate=True)
+    state = jax.ShapeDtypeStruct(fused_state_shape(n), jnp.float32,
+                                 sharding=one_chip)
+    compiled, _ = _compile(fn, state)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == sweeps
+    seen = set()
+    for line in calls:
+        name = re.match(r"\s*%(\S+) = ", line).group(1)
+        assert re.match(r"quest_seg_\w+_r\d+s\d+_[0-9a-f]{8}(\.\d+)?$",
+                        name), name
+        scope = re.search(r'op_name="[^"]*quest\.sweep(\d\d)/'
+                          r'(quest_seg_\w+)/', line)
+        assert scope, line[:300]
+        assert name.startswith(scope.group(2))
+        seen.add(int(scope.group(1)))
+    assert seen == set(range(sweeps))
+
+
 def test_batched_trajectory_kernel_20q(one_chip):
     from quest_tpu import trajectories as T
     from quest_tpu.circuit import Circuit

@@ -49,19 +49,6 @@ def test_sample_validation():
         meas.sample(q, 0, jax.random.PRNGKey(0))
 
 
-def test_op_metrics_reports_bytes():
-    q = qt.create_qureg(10)
-
-    def step(amps):
-        from quest_tpu.ops import apply as A
-        import quest_tpu.ops.matrices as M
-        from quest_tpu import cplx
-        return A.apply_matrix(amps, 10, cplx.pack(M.HADAMARD), (3,))
-
-    metrics = profiling.op_metrics(step, q.amps)
-    assert isinstance(metrics, dict)  # backend-dependent contents
-
-
 @pytest.mark.slow          # ~29 s: the heaviest single test on this
                            # host — tier-1 budget discipline (runs in
                            # the full CI suite step)
