@@ -457,10 +457,10 @@ def _estimate_ms(parts, n, model=None):
         if isinstance(st, PB.PairStage):
             return model["pair"]
         if isinstance(st, PB.MultiPhaseStage):
-            # PROJECTED from the measured per-phase constant, not yet
-            # calibrated on chip: each row keeps the mask-accumulate
-            # (~1/3 of a lone phase stage's mask + trig blend), and the
-            # trig + complex multiply tail is paid once for the group
+            # PROJECTED from the measured per-phase constant, not
+            # calibrated: the v5e measured 33.7 ms (trig path) and
+            # 15.2 ms (trig-free) for m=2 behind an scb128 stage, against
+            # this 7.2 (docs/KERNELS.md); recalibration is ROADMAP D6
             return model["phase"] * (0.7 + 0.3 * len(st.forms))
         return model["phase"]
 

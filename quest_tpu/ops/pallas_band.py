@@ -68,7 +68,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from quest_tpu import precision
+from quest_tpu import precision, profiling
 from quest_tpu.ops import fusion as F
 
 
@@ -100,6 +100,12 @@ MAX_SEGMENT_STAGES = 32  # stages per kernel launch: operand blocks are
 # otherwise accumulate hundreds of operands per segment
 VMEM_LIMIT_BYTES = 100 * (1 << 20)  # v5e has 128 MiB VMEM; the default
 # 16 MiB scoped limit rejects multi-stage kernels (measured round 1/2)
+MULTIPHASE_TRIGFREE_MAX = 3  # MultiPhaseStage terms up to which the
+# stage selects among products of unit factors instead of taking cos/sin
+# of a per-element angle sum (_apply_multiphase_stage). Measured on v5e at
+# 30q behind an scb128 stage (docs/KERNELS.md): at m = 3 the select tree
+# is 15 ms (allones) and 9 ms (parity) cheaper; at m = 4 parity groups
+# are 8 ms dearer, and at m = 8 its temporaries overflow scoped VMEM
 
 
 def plan_bands(n: int) -> List[Tuple[int, int]]:
@@ -183,13 +189,15 @@ class PairStage:
 
 @dataclasses.dataclass(frozen=True)
 class MultiPhaseStage:
-    """A scheduler-composed GROUP of unit phases in ONE stage, applied
-    ADDITIVELY: each row contributes an angle (an allones row adds its
-    theta where all masked bits are 1; a parity row adds -half*(-1)^par)
-    and the stage pays cos/sin + one complex multiply ONCE for the whole
-    group — m mask-accumulates instead of m full phase stages (each with
-    its own trig blend), and ONE stage against MAX_SEGMENT_STAGES
-    instead of m. The (m, 8) operand rows are
+    """A scheduler-composed GROUP of unit phases in ONE stage: each row
+    contributes an angle (an allones row adds its theta where all masked
+    bits are 1; a parity row adds -half*(-1)^par), and the group costs
+    ONE stage against MAX_SEGMENT_STAGES instead of m. Up to
+    MULTIPHASE_TRIGFREE_MAX rows the kernel selects each element's
+    product of the rows' unit factors from the 2^m products (cos/sin
+    taken of the m angles alone); wider groups accumulate
+    the angle per element and pay one full-tile cos/sin (the chip's
+    crossover, docs/KERNELS.md). The (m, 8) operand rows are
     [angle, lane_mask, row_mask_lo, row_mask_hi, 0, 0, 0, 0] (row masks
     split at bit 15 so each half is exact in f32); `forms` carries the
     static per-row interpretation: 'a' = allones, 'p' = parity."""
@@ -1287,31 +1295,64 @@ def _apply_parity_stage(re, im, st: ParityStage, gref, row_ids):
     return nre, nim
 
 
+def multiphase_trigfree(st: MultiPhaseStage) -> bool:
+    """Whether `st` runs on the trig-free path: a static choice on its
+    term count, the one shape the kernel sees."""
+    return len(st.forms) <= MULTIPHASE_TRIGFREE_MAX
+
+
 def _apply_multiphase_stage(re, im, st: MultiPhaseStage, gref, row_ids):
     # (m, 8) operand rows: [angle, lane_mask, row_mask_lo, row_mask_hi,
-    # 0, 0, 0, 0]; st.forms[r] picks the static interpretation. The
-    # group's total angle accumulates per element, then ONE cos/sin +
-    # complex multiply applies the whole group (vs one trig blend per
-    # phase when each rides its own Phase/ParityStage).
+    # 0, 0, 0, 0]; st.forms[r] picks the static interpretation. Lane
+    # predicates stay (1, 128) and row predicates (rows, 1) until the
+    # one broadcast that combines them.
     g = gref[...]
     lane = _lane_iota()
-    tot = None
-    for r, form in enumerate(st.forms):
-        ang = g[r, 0]
+
+    def term(r, form):
+        # allones: the match mask; parity: the parity bit
         lm = g[r, 1].astype(jnp.int32)
         rm = _row_halves(g[r, 2], g[r, 3])
         if form == "a":
-            match = ((lane & lm) == lm) & ((row_ids & rm) == rm)
-            contrib = jnp.where(match, ang, 0.0)
-        else:
-            par = _xor_fold(lane & lm, 4) ^ _xor_fold(row_ids & rm, 16)
-            sign = 1.0 - 2.0 * par.astype(jnp.float32)
-            contrib = ang * sign
-        tot = contrib if tot is None else tot + contrib
-    cosf = jnp.cos(tot)
-    sinf = jnp.sin(tot)
-    nre = re * cosf - im * sinf
-    nim = re * sinf + im * cosf
+            return ((lane & lm) == lm) & ((row_ids & rm) == rm)
+        return _xor_fold(lane & lm, 4) ^ _xor_fold(row_ids & rm, 16)
+
+    if not multiphase_trigfree(st):
+        # wide groups: accumulate the total angle per element, then ONE
+        # cos/sin + complex multiply (the select tree doubles per term)
+        tot = None
+        for r, form in enumerate(st.forms):
+            ang, t = g[r, 0], term(r, form)
+            if form == "a":
+                contrib = jnp.where(t, ang, 0.0)
+            else:
+                contrib = ang * (1.0 - 2.0 * t.astype(jnp.float32))
+            tot = contrib if tot is None else tot + contrib
+        fre, fim = jnp.cos(tot), jnp.sin(tot)
+    else:
+        # each term picks one of two constants by one bit per element:
+        # allones 1 or e^{i ang} by its match, parity e^{i ang} or
+        # e^{-i ang} by its parity. cos/sin of the m angles only (one
+        # vreg); the 2^m products of the constants are scalars, and a
+        # select tree (2^m - 1 selects a plane) picks each element's.
+        ang = g[:, 0:1]
+        cos_a, sin_a = jnp.cos(ang), jnp.sin(ang)
+        bits = []
+        table = [(1.0, 0.0)]     # entry j: the product for bit pattern j
+        for r, form in enumerate(st.forms):
+            c, s, t = cos_a[r, 0], sin_a[r, 0], term(r, form)
+            bits.append(t if form == "a" else t != 0)
+            pair = ((1.0, 0.0), (c, s)) if form == "a" else ((c, s), (c, -s))
+            table = [(tr * ur - ti * ui, tr * ui + ti * ur)
+                     for ur, ui in pair for tr, ti in table]
+        for bit in reversed(bits):
+            half = len(table) // 2
+            table = [(jnp.where(bit, hi[0], lo[0]),
+                      jnp.where(bit, hi[1], lo[1]))
+                     for lo, hi in zip(table[:half], table[half:])]
+        (fre, fim), = table
+    nre = re * fre - im * fim
+    nim = re * fim + im * fre
     return nre, nim
 
 
@@ -2121,7 +2162,14 @@ def compile_segment_cached(cache: dict, stages: Sequence, n: int,
     STRUCTURE (operand values ride as kernel inputs), so segments that
     differ only in values — e.g. RCS layers with different angles —
     share one compiled kernel. The ONE place the cache key lives
-    (batch is part of it: a bucket's kernels are shaped for it)."""
+    (batch is part of it: a bucket's kernels are shaped for it).
+    Counts each call's MultiPhaseStages by path, hit or miss, into the
+    active recording: `quest.multiphase_trigfree` / `quest.multiphase_trig`."""
+    for st in stages:
+        if isinstance(st, MultiPhaseStage):
+            profiling.count("quest.multiphase_trigfree"
+                            if multiphase_trigfree(st)
+                            else "quest.multiphase_trig")
     key = (tuple(stages), n, interpret, batch)
     fn = cache.get(key)
     if fn is None:

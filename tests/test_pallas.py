@@ -13,6 +13,8 @@ from quest_tpu.ops import fusion as F
 from quest_tpu.ops import pallas_band as PB
 from quest_tpu.state import to_dense
 
+from . import oracle
+
 N = 10  # 8 rows x 128 lanes — the smallest cleanly-tiled register
 
 
@@ -786,3 +788,95 @@ def test_laneblock_chunked_sweep_matches():
         np.testing.assert_allclose(
             np.asarray(chunked).reshape(2, -1), np.asarray(want),
             atol=2e-5, rtol=0)
+
+
+# -- MultiPhaseStage: trig-free unit factors up to the crossover, the
+#    per-element angle sum with one cos/sin above it --------------------------
+
+_MP_N = 13
+_MP_KERNELS = {}     # forms -> jitted kernel: operands are data
+
+
+def _mp_qubits(rng, masks):
+    lane = rng.choice(PB.LANE_QUBITS, size=rng.integers(1, 3),
+                      replace=False)
+    row = rng.choice(np.arange(PB.LANE_QUBITS, _MP_N),
+                     size=rng.integers(1, 3), replace=False)
+    return {"lane": list(lane), "row": list(row),
+            "mixed": [lane[0], row[0]]}[masks]
+
+
+def _mp_want(vec, forms, rows):
+    """The group's phase per basis index, from the operand rows alone."""
+    idx = np.arange(1 << _MP_N)
+    phase = np.zeros(1 << _MP_N)
+    for form, (ang, lm, rlo, rhi) in zip(forms, rows):
+        mask = int(lm) | ((int(rlo) | (int(rhi) << 15)) << PB.LANE_QUBITS)
+        if form == "a":
+            phase += np.where(idx & mask == mask, ang, 0.0)
+        else:
+            par = np.zeros_like(idx)
+            for b in range(_MP_N):
+                par ^= ((idx & mask) >> b) & 1
+            phase += ang * (1 - 2 * par)
+    return vec * np.exp(1j * phase)
+
+
+@pytest.mark.parametrize("m", [1, 2, PB.MULTIPHASE_TRIGFREE_MAX,
+                               PB.MULTIPHASE_TRIGFREE_MAX + 1])
+@pytest.mark.parametrize("angle", ["pi", "pi/4", "random"])
+@pytest.mark.parametrize("masks", ["lane", "row", "mixed"])
+@pytest.mark.parametrize("form", ["a", "p"])
+def test_multiphase_stage_matches_oracle(form, masks, angle, m):
+    """One MultiPhaseStage segment on a random 13-qubit state (4 blocks,
+    so row bits come from the grid too) against the phases its operand
+    rows define; both sides of the crossover."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng([m, len(masks), len(angle), ord(form)])
+    forms = (form,) * m
+    st = PB.MultiPhaseStage(forms)
+    assert PB.multiphase_trigfree(st) == (m <= PB.MULTIPHASE_TRIGFREE_MAX)
+    rows = []
+    for _ in range(m):
+        ang = {"pi": np.pi, "pi/4": np.pi / 4,
+               "random": rng.uniform(-np.pi, np.pi)}[angle]
+        qs = _mp_qubits(rng, masks)
+        lm = sum(1 << int(q) for q in qs if q < PB.LANE_QUBITS)
+        rm = sum(1 << (int(q) - PB.LANE_QUBITS) for q in qs
+                 if q >= PB.LANE_QUBITS)
+        rows.append([ang, lm, rm & 0x7FFF, rm >> 15, 0, 0, 0, 0])
+    operand = np.array(rows, dtype=np.float32)
+    if forms not in _MP_KERNELS:
+        _MP_KERNELS[forms] = jax.jit(PB.compile_segment(
+            [st], _MP_N, rows_eff_bits=4, interpret=True))
+    vec = oracle.random_statevector(_MP_N, rng).astype(np.complex64)
+    amps = np.stack([vec.real, vec.imag]).reshape(2, -1, PB.LANES)
+    out = np.asarray(_MP_KERNELS[forms](jnp.asarray(amps), [operand]))
+    got = (out[0] + 1j * out[1]).reshape(-1)
+    want = _mp_want(vec.astype(np.complex128), forms,
+                    operand[:, :4].astype(np.float64))
+    scale = float(np.max(np.abs(want)))
+    np.testing.assert_allclose(got, want, atol=1e-5 * scale, rtol=0)
+
+
+def test_multiphase_counters_name_each_path():
+    """compile_segment_cached counts each MultiPhaseStage by path on a
+    hit and a miss alike; an RCS plan's CZ pairs all take the trig-free
+    path."""
+    from quest_tpu import profiling
+    c = random_circuit(16, 20, seed=7, entangler="cz")
+    with profiling.recording() as rec:
+        c.compiled_fused(16, False, donate=False, interpret=True)
+    assert rec.counts.get("quest.multiphase_trigfree", 0) == 3
+    assert "quest.multiphase_trig" not in rec.counts
+    x = PB.MULTIPHASE_TRIGFREE_MAX
+    cache = {}
+    with profiling.recording() as rec:
+        for m in (x, x + 1, x + 1):
+            PB.compile_segment_cached(
+                cache, (PB.MultiPhaseStage(("a",) * m),), 16,
+                interpret=True)
+    assert len(cache) == 2
+    assert rec.counts == {"quest.multiphase_trigfree": 1,
+                          "quest.multiphase_trig": 2}
