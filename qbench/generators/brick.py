@@ -1,8 +1,8 @@
-"""The one circuit generator: a traffic file's parameters to a gate list.
+"""Brick circuits, the generator of a traffic file that names none.
 
 A traffic file (qbench/traffic/<name>.json) fixes the circuit as an
 architecture is fixed for a model; the register size comes from the
-configuration. The generator makes brick circuits: in each layer a random
+configuration. This generator makes brick circuits: in each layer a random
 rotation on every qubit, then an entangler on the pairs that start at
 layer mod 2, with optional one-qubit noise after each rotation, after
 each entangler (on both its qubits) and at the end of the layer.
@@ -15,15 +15,11 @@ the program.
 
 from __future__ import annotations
 
-from typing import List, NamedTuple, Tuple
+from typing import List
 
 import numpy as np
 
-
-class Op(NamedTuple):
-    name: str                 # rx | ry | rz | cz | depolarising | damping
-    qubits: Tuple[int, ...]
-    param: float | None = None
+from qbench.generators import Op
 
 
 def brick_circuit(traffic: dict, num_qubits: int) -> List[Op]:
@@ -51,3 +47,6 @@ def brick_circuit(traffic: dict, num_qubits: int) -> List[Op]:
         for q in range(num_qubits):
             channel("end_of_layer", q)
     return ops
+
+
+ops = brick_circuit

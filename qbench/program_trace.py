@@ -5,9 +5,9 @@ and where one traced application's device time goes, sweep by sweep.
     python3 qbench/program_trace.py --workload <cell> --seed <n>
         [--record 0|1] [--trace 0|1]
 
-Set-up is qbench/run.py's: the cell's circuit through
-Circuit.compiled_fused, lower(), compile() or a cache load, the seeded
-input and one warm-up application. With --record 1 the program's
+Set-up is qbench/run.py's (run.setup): the cell's circuit through its
+register's program, lower(), compile() or a cache load, the seeded input;
+then one warm-up application. With --record 1 the program's
 recording (quest_tpu.profiling.recording) is open around all of it. With
 --trace 1 one more application runs under the profiler, with the
 profiler settings run.py uses. There is no window and no check: this is
@@ -169,44 +169,28 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
     import contextlib
 
     import jax
-    import jax.numpy as jnp
 
-    from qbench import circuits, run as RUN, trace as TR
+    from qbench import run as RUN, trace as TR
     from quest_tpu import profiling
     from quest_tpu.ops import pallas_band as PB
-    from quest_tpu.state import fused_state_shape
 
     device = RUN.device_record(cell["chips"], require_tpu)
     init_s = time.perf_counter() - T0
-    reg = __import__(f"qbench.registers.{config['register']}",
-                     fromlist=["_"])
-    nq = config["qubits"]
-    n = reg.state_bits(nq)
-    density = config["register"] == "density"
-    ops = circuits.brick_circuit(traffic, nq)
     with (profiling.recording() if record
           else contextlib.nullcontext()) as rec:
-        circuit = RUN.build_circuit(ops, nq)
-        fn = circuit.compiled_fused(n, density, donate=True,
-                                    interpret=interpret)
-        state = reg.program_input(reg.random_factors(RUN._rng(seed, 0), nq),
-                                  jnp.zeros(fused_state_shape(n),
-                                            jnp.float32),
-                                  num_qubits=nq)
+        s = RUN.setup(config, traffic, seed=seed, interpret=interpret)
+        reg, nq, compiled = s.reg, s.num_qubits, s.compiled
         t = time.perf_counter()
-        compiled = fn.lower(state).compile()
-        compile_s = time.perf_counter() - t
-        t = time.perf_counter()
-        state = compiled(state)
+        state = compiled(s.state)
         state.block_until_ready()
         warmup_s = time.perf_counter() - t
     setup_s = time.perf_counter() - T0
     out = {"device": device, "record": record, "setup_s": setup_s,
-           "init_s": init_s, "compile_s": compile_s, "warmup_s": warmup_s}
+           "init_s": init_s, "compile_s": s.compile_s, "warmup_s": warmup_s}
     if record:
         out["setup"] = setup_split(rec)
     if trace:
-        steps = planned_steps(circuit, n, density)
+        steps = planned_steps(s.circuit, reg.state_bits(nq), reg.DENSITY)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         with (profiling.recording() if record
               else contextlib.nullcontext()) as window:

@@ -10,8 +10,10 @@ chunk in place, so that it fits beside nothing: at 30 bits the state is
 Gates on disjoint positions commute, so consecutive ops are multiplied
 into one dense matrix per fixed window of positions (textbook Kronecker
 algebra, 2^w x 2^w with w <= 8) and each window is one matmul pass at
-HIGHEST precision. A diagonal op that straddles two windows (a CZ across
-a window edge) is applied as an elementwise phase pass.
+HIGHEST precision. The gates' matrices come from qbench/gates. An op
+that straddles two windows is applied on its own: a diagonal one (a CZ
+across a window edge) as an elementwise phase pass, any other (a swap
+too) as one chunked in-place pass over the positions it touches.
 
 The comparison is a seeded sketch of the whole output: 16 projections of
 every amplitude onto pseudo-random signs of its natural index, taken the
@@ -24,7 +26,7 @@ block or a lower precision shows in it.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -34,36 +36,7 @@ from jax import lax
 SKETCH = 16               # projections in the sketch
 CHUNK = 1 << 24           # elements per chunk of a reference pass (128 MiB)
 SKETCH_CHUNK = 1 << 20    # elements per chunk of the sketch
-
-# -- textbook gates and channels ---------------------------------------------
-
-_I = np.eye(2, dtype=np.complex128)
-_X = np.array([[0, 1], [1, 0]], dtype=np.complex128)
-_Y = np.array([[0, -1j], [1j, 0]], dtype=np.complex128)
-_Z = np.array([[1, 0], [0, -1]], dtype=np.complex128)
-
-
-def unitary(name: str, param) -> Tuple[np.ndarray, bool]:
-    """(matrix, is_diagonal); local index bit j is the op's j-th qubit."""
-    if name in ("rx", "ry", "rz"):
-        axis = {"rx": _X, "ry": _Y, "rz": _Z}[name]
-        u = np.cos(param / 2) * _I - 1j * np.sin(param / 2) * axis
-        return u, name == "rz"
-    if name == "cz":
-        return np.diag([1, 1, 1, -1]).astype(np.complex128), True
-    raise KeyError(f"the reference has no unitary {name!r}")
-
-
-def kraus(name: str, p: float) -> List[np.ndarray]:
-    """QuEST's mixDepolarising and mixDamping, from their definitions."""
-    if name == "depolarising":
-        return [np.sqrt(1 - p) * _I] + [np.sqrt(p / 3) * s
-                                        for s in (_X, _Y, _Z)]
-    if name == "damping":
-        return [np.array([[1, 0], [0, np.sqrt(1 - p)]], dtype=np.complex128),
-                np.array([[0, np.sqrt(p)], [0, 0]], dtype=np.complex128)]
-    raise KeyError(f"the reference has no channel {name!r}")
-
+CROSS_CHUNK = CHUNK // 8  # of a cross pass, whose axes pad up to 8 times
 
 # -- passes ------------------------------------------------------------------
 
@@ -92,7 +65,8 @@ def _embed(mat: np.ndarray, pos: Sequence[int], lo: int, w: int):
 
 def plan_passes(ops, wins) -> List[tuple]:
     """Lowered ops [(positions, matrix, is_diagonal)] in circuit order to
-    passes [("window", {(lo, hi): matrix}) | ("diag", [(positions, d)])]."""
+    passes [("window", {(lo, hi): matrix}) | ("diag", [(positions, d)]) |
+    ("cross", [(positions, matrix)])]."""
     passes: List[tuple] = []
     mats: Dict[tuple, np.ndarray] = {}
     diag: List[tuple] = []
@@ -106,10 +80,12 @@ def plan_passes(ops, wins) -> List[tuple]:
         mats, diag = {}, []
 
     for pos, mat, is_diag in ops:
-        if set(pos) & {p for d_pos, _ in diag for p in d_pos}:
-            flush()
         win = next((w for w in wins if w[0] <= min(pos) and max(pos) < w[1]),
                    None)
+        # diagonal ops commute: one across windows joins the pending ones
+        if (win is not None or not is_diag) and \
+                set(pos) & {p for d_pos, _ in diag for p in d_pos}:
+            flush()
         if win is not None:
             lo, hi = win
             g = _embed(mat, pos, lo, hi - lo)
@@ -117,8 +93,8 @@ def plan_passes(ops, wins) -> List[tuple]:
         elif is_diag:
             diag.append((tuple(pos), np.diag(mat).copy()))
         else:
-            raise NotImplementedError(
-                f"a non-diagonal op on positions {pos} spans two windows")
+            flush()
+            passes.append(("cross", [(pos, mat)]))
     flush()
     return passes
 
@@ -179,6 +155,76 @@ def _window_pass(x, g, lo, hi, lane_bits):
     return lax.fori_loop(0, a * nc, body, v).reshape(x.shape)
 
 
+def _cross_operand(mat, pos, lane_bits):
+    """An op across windows as (its row bits, its matrix) for _cross_pass:
+    local bits reordered so that its lane positions come first, then
+    (2^r, 2^r) over the r row bits where it touches no lane, else
+    (2^r, 2^L, 2^r, 2^L), each row-bit block embedded on the lanes."""
+    k = len(pos)
+    order = sorted(range(k), key=lambda j: pos[j] >= lane_bits)
+    idx = np.arange(1 << k)
+    old = sum(((idx >> t) & 1) << j for t, j in enumerate(order))
+    g = np.asarray(mat)[old[:, None], old[None, :]]
+    lane_pos = [pos[j] for j in order if pos[j] < lane_bits]
+    rows = tuple(pos[j] - lane_bits for j in order if pos[j] >= lane_bits)
+    if not lane_pos:
+        return rows, g
+    r, kl = 1 << len(rows), 1 << len(lane_pos)
+    blocks = g.reshape(r, kl, r, kl).transpose(0, 2, 1, 3)
+    big = np.stack([np.stack([_embed(b, lane_pos, 0, lane_bits) for b in row])
+                    for row in blocks])
+    return rows, big.transpose(0, 2, 1, 3)
+
+
+def _cross_pass(x, g, row_bits, lane_bits):
+    """x: (2, R, 2^lane_bits) f32 planes; g, row_bits: _cross_operand's. In
+    place, by chunks: each chunk is a block of 2^c consecutive rows for
+    each value of the op's row bits at or above c; the op's lower row
+    bits become axes of the chunk, and the lanes stay the minor axis (one
+    with fewer than 8 rows above it pads at most 8 times)."""
+    _, rows, lanes = x.shape
+    r = len(row_bits)
+    c = min(rows.bit_length() - 1,
+            max(0, (CROSS_CHUNK // lanes).bit_length() - 1 - r))
+    high = [b for b in row_bits if b >= c]
+    low = sorted((b for b in row_bits if b < c), reverse=True)
+    offsets = [sum(((m >> t) & 1) << b for t, b in enumerate(high))
+               for m in range(1 << len(high))]
+    # the chunk's axes: high bits (high[-1] first), then the rows split
+    # at each low bit, then the lanes
+    sizes, axis, top = [2] * len(high), {}, c
+    for t, b in enumerate(high):
+        axis[b] = len(high) - 1 - t
+    for b in low:
+        sizes += [1 << (top - b - 1), 2]
+        axis[b] = len(sizes) - 1
+        top = b
+    sizes.append(1 << top)
+    op_axes = [axis[b] for b in reversed(row_bits)]
+    order = op_axes + [a for a in range(len(sizes)) if a not in op_axes]
+    perm = [0] + [a + 1 for a in order] + [len(sizes) + 1]
+    moved = [2] + [sizes[a] for a in order] + [lanes]
+    spec = "Rr,rsl->Rsl" if g.ndim == 3 else "RLrl,rsl->RsL"
+
+    def base(i):
+        v = i << c
+        for b in sorted(high):
+            v = ((v >> b) << (b + 1)) | (v & ((1 << b) - 1))
+        return v
+
+    def body(i, x):
+        start = base(i)
+        blocks = jnp.stack([lax.dynamic_slice_in_dim(x, start + o, 1 << c, 1)
+                            for o in offsets], 1)
+        v = blocks.reshape(2, *sizes, lanes).transpose(perm)
+        v = _cmul(spec, g, v.reshape(2, 1 << r, -1, lanes))
+        v = v.reshape(moved).transpose(np.argsort(perm)).reshape(blocks.shape)
+        for m, o in enumerate(offsets):
+            x = lax.dynamic_update_slice_in_dim(x, v[:, m], start + o, 1)
+        return x
+    return lax.fori_loop(0, rows >> (c + len(high)), body, x)
+
+
 def _positions(i, rc, lanes):
     r = lax.broadcasted_iota(jnp.uint32, (rc, lanes), 0)
     l = lax.broadcasted_iota(jnp.uint32, (rc, lanes), 1)
@@ -223,17 +269,24 @@ def reference_program(passes, lane_bits):
         if kind == "window":
             shape.append(("window", tuple(body)))
             arrays.append([jnp.asarray(_planes(m)) for m in body.values()])
-        else:
+        elif kind == "diag":
             shape.append(("diag", tuple(p for p, _ in body)))
             arrays.append([jnp.asarray(_planes(d)) for _, d in body])
+        elif kind == "cross":
+            ops = [_cross_operand(m, p, lane_bits) for p, m in body]
+            shape.append(("cross", tuple(rows for rows, _ in ops)))
+            arrays.append([jnp.asarray(_planes(g)) for _, g in ops])
 
     def run(x, arrays):
         for (kind, keys), arrs in zip(shape, arrays):
             if kind == "window":
                 for (lo, hi), g in zip(keys, arrs):
                     x = _window_pass(x, g, lo, hi, lane_bits)
-            else:
+            elif kind == "diag":
                 x = _diag_pass(x, list(zip(keys, arrs)))
+            else:
+                for rows, g in zip(keys, arrs):
+                    x = _cross_pass(x, g, rows, lane_bits)
         return x
 
     return jax.jit(run, donate_argnums=(0,)), arrays

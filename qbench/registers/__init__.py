@@ -4,9 +4,34 @@ Each module gives the reference's layout of the state (LANE_BITS, the
 windows of its passes and `layout`, which natural bit each position holds),
 seeded product inputs built on the device in the program's layout and in
 the reference's, and `lower`, which turns the traffic's ops into the
-reference's matrices on positions."""
+reference's matrices on positions. Each also gives the program's side:
+DENSITY, `program(circuit, num_qubits, interpret)` (the compiled entry
+the window drives, before lower()), `program_buffer(num_qubits)` (a
+buffer of the program's state, for the first input to overwrite) and
+`state_bytes(num_qubits)`, which `fused_engine` makes for a register the
+fused engine runs whole; and SMALL_QUBITS, the register size of the CPU
+tests."""
 
 import jax.numpy as jnp
+
+
+def fused_engine(state_bits, density: bool):
+    """(program, program_buffer, state_bytes) of a register that
+    Circuit.compiled_fused runs whole on one chip, in f32 (re, im)
+    planes of state_bits(num_qubits) bits."""
+    def program(circuit, num_qubits: int, interpret: bool):
+        return circuit.compiled_fused(state_bits(num_qubits), density,
+                                      donate=True, interpret=interpret)
+
+    def program_buffer(num_qubits: int):
+        from quest_tpu.state import fused_state_shape
+        return jnp.zeros(fused_state_shape(state_bits(num_qubits)),
+                         jnp.float32)
+
+    def state_bytes(num_qubits: int) -> int:
+        return 8 << state_bits(num_qubits)
+
+    return program, program_buffer, state_bytes
 
 
 def kron(vs):

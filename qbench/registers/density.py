@@ -15,13 +15,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from qbench import reference as R
-from qbench.registers import kron, outer_planes
+from qbench.gates import gate
+from qbench.registers import fused_engine, kron, outer_planes
 
 LANE_BITS = 8
+DENSITY = True
+SMALL_QUBITS = 5
 
 
 def state_bits(num_qubits: int) -> int:
     return 2 * num_qubits
+
+
+program, program_buffer, state_bytes = fused_engine(state_bits, DENSITY)
 
 
 def windows(num_qubits: int):
@@ -85,10 +91,11 @@ def lower(ops, num_qubits):
     for op in ops:
         ket = tuple(2 * q for q in op.qubits)
         bra = tuple(2 * q + 1 for q in op.qubits)
-        if op.name in ("depolarising", "damping"):
-            ks, diag = R.kraus(op.name, op.param), False
+        g = gate(op.name)
+        if hasattr(g, "kraus"):
+            ks, diag = g.kraus(op.param), False
         else:
-            u, diag = R.unitary(op.name, op.param)
+            u, diag = g.matrix(op.param)
             ks = [u]
         sup = sum(np.kron(np.conj(k), k) for k in ks)
         out.append((ket + bra, sup, diag))

@@ -11,13 +11,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from qbench import reference as R
-from qbench.registers import kron, outer_planes
+from qbench.gates import gate
+from qbench.registers import fused_engine, kron, outer_planes
 
 LANE_BITS = 7
+DENSITY = False
+SMALL_QUBITS = 10
 
 
 def state_bits(num_qubits: int) -> int:
     return num_qubits
+
+
+program, program_buffer, state_bytes = fused_engine(state_bits, DENSITY)
 
 
 def windows(num_qubits: int):
@@ -59,6 +65,6 @@ def reference_input(factors, num_qubits):
 def lower(ops, num_qubits):
     out = []
     for op in ops:
-        mat, diag = R.unitary(op.name, op.param)
+        mat, diag = gate(op.name).matrix(op.param)
         out.append((tuple(op.qubits), mat, diag))
     return out

@@ -3,21 +3,27 @@
 
     python3 qbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell names a configuration (qbench/configs/<name>.json: the register)
-and a traffic mix (qbench/traffic/<name>.json: the circuit); per-layer
+The cell names a configuration (qbench/configs/<name>.json: the register,
+whose kind is qbench/registers/<register>.py) and a traffic mix
+(qbench/traffic/<name>.json: the circuit, made by
+qbench/generators/<generator>.py from gates of qbench/gates); per-layer
 metrics are read by qbench/metrics/<name>.py, and the limit of each number
-compared by qbench/cells/<cell>.json. A later PR adds a cell by adding
-files and entries.
+compared by qbench/cells/<cell>.json. A cell comes in as such files and
+its entry in BENCHMARK.json.
 
-Set-up: JAX and the chip, the circuit through Circuit.compiled_fused (plan,
-compile or cache load), the seeded input on the device and one warm-up
-application. The window is a closed loop with one client: build a fresh
-seeded input state on the device in the buffer the last output held
+Set-up: JAX and the chip, the circuit through the register's program
+(plan, compile or cache load), the seeded input on the device and one
+warm-up application. The window is a closed loop with one client: build a
+fresh seeded input state on the device in the buffer the last output held
 (donated, so no 8 GiB buffer is freed or allocated), apply the compiled
 program (donated), await it, until --seconds have passed; apply_s is the
 window's length over the applications completed.
 Then the last application's output is sketched, freed, and the plain
-reference (qbench/reference.py) recomputes it from the same input.
+reference (qbench/reference.py) recomputes it from the same input. With
+--trace 1 the per-layer readers then also get the program's own recording
+(quest_tpu.profiling.recording) of a second build and lowering of the
+circuit: the set-up, which compile_s times, runs without it, as in every
+run.
 
 The last line of stdout is the result; the numbers compared, with their
 limits, are the last lines of stderr and the last key of the result.
@@ -37,6 +43,7 @@ import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+from typing import NamedTuple  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -80,17 +87,65 @@ def device_record(chips: int, require_tpu: bool) -> dict:
 
 def build_circuit(ops, num_qubits):
     """The traffic's ops through the program's public builder."""
+    from qbench.gates import gate
     from quest_tpu.circuit import Circuit
     c = Circuit(num_qubits)
     for op in ops:
-        args = op.qubits if op.param is None else (*op.qubits, op.param)
-        getattr(c, op.name)(*args)
+        gate(op.name).build(c, op.qubits, op.param)
     return c
 
 
 def _rng(seed: int, j: int):
     import numpy as np
     return np.random.default_rng([seed % (1 << 64), j])
+
+
+class Setup(NamedTuple):
+    reg: object               # the register's module
+    num_qubits: int
+    ops: list                 # the traffic's ops, generators.Op
+    circuit: object           # the program's Circuit
+    compiled: object          # the compiled program the window drives
+    state: object             # its first input, seeded
+    lower_s: float
+    compile_s: float          # lower() + compile()
+
+
+def setup(config: dict, traffic: dict, *, seed: int,
+          interpret: bool = False) -> Setup:
+    """A cell's configuration and traffic to its compiled program, its
+    first input and its op list."""
+    from qbench import generators
+
+    reg = importlib.import_module(f"qbench.registers.{config['register']}")
+    nq = config["qubits"]
+    ops = generators.ops(traffic, nq)
+    circuit = build_circuit(ops, nq)
+    fn = reg.program(circuit, nq, interpret)
+    state = reg.program_input(reg.random_factors(_rng(seed, 0), nq),
+                              reg.program_buffer(nq), num_qubits=nq)
+    t = time.perf_counter()
+    lowered = fn.lower(state)
+    lower_s = time.perf_counter() - t
+    compiled = lowered.compile()
+    return Setup(reg, nq, ops, circuit, compiled, state, lower_s,
+                 time.perf_counter() - t)
+
+
+def program_record(s: Setup, interpret: bool = False):
+    """The program's recording (quest_tpu.profiling.recording) of a fresh
+    build of the circuit through the register's program, and its lower(),
+    a second time in this process. Not compile(): a lowering from here
+    has a persistent-cache key of its own (the call site is part of it),
+    so it would compile the whole program once more in a fresh
+    checkout."""
+    import jax
+    from quest_tpu import profiling
+    with profiling.recording() as rec:
+        fn = s.reg.program(build_circuit(s.ops, s.num_qubits), s.num_qubits,
+                           interpret)
+        fn.lower(jax.ShapeDtypeStruct(s.state.shape, s.state.dtype))
+    return rec
 
 
 def run_cell(cell: dict, config: dict, traffic: dict, metrics: list,
@@ -103,9 +158,8 @@ def run_cell(cell: dict, config: dict, traffic: dict, metrics: list,
     import jax
     import jax.numpy as jnp
 
-    from qbench import circuits, reference as R
+    from qbench import reference as R
     from qbench import trace as TR
-    from quest_tpu.state import fused_state_shape
 
     device = device_record(cell["chips"], require_tpu)
     try:
@@ -113,27 +167,14 @@ def run_cell(cell: dict, config: dict, traffic: dict, metrics: list,
     except KeyError:
         raise KeyError(f"qbench/peaks.json has no row for device kind "
                        f"{device['kind']!r}") from None
-    reg = importlib.import_module(f"qbench.registers.{config['register']}")
-    nq = config["qubits"]
-    n = reg.state_bits(nq)
-    density = config["register"] == "density"
-    ops = circuits.brick_circuit(traffic, nq)
 
     def span(name):
         return jax.profiler.TraceAnnotation(TR.SPAN + name)
 
-    circuit = build_circuit(ops, nq)
-    fn = circuit.compiled_fused(n, density, donate=True, interpret=interpret)
-    state = reg.program_input(reg.random_factors(_rng(seed, 0), nq),
-                              jnp.zeros(fused_state_shape(n), jnp.float32),
-                              num_qubits=nq)
-    t = time.perf_counter()
-    lowered = fn.lower(state)
-    lower_s = time.perf_counter() - t
-    compiled = lowered.compile()
-    compile_s = time.perf_counter() - t
-    run = compiled if program_hook is None else program_hook(compiled)
-    state = run(state)
+    s = setup(config, traffic, seed=seed, interpret=interpret)
+    reg, nq = s.reg, s.num_qubits
+    run = s.compiled if program_hook is None else program_hook(s.compiled)
+    state = run(s.state)
     state.block_until_ready()
     setup_s = time.perf_counter() - T0
 
@@ -159,8 +200,9 @@ def run_cell(cell: dict, config: dict, traffic: dict, metrics: list,
     if trace:
         jax.profiler.stop_trace()
     apply_s = (t_end - t_start) / apps
-    stats = jax.devices()[0].memory_stats() or {}
-    device["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
+    device["memory_peak_bytes"] = max(
+        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for d in jax.devices()[:cell["chips"]])
 
     summary, extra = None, {}
     if trace:
@@ -179,13 +221,13 @@ def run_cell(cell: dict, config: dict, traffic: dict, metrics: list,
         salt = jnp.uint32(seed % (1 << 32))
         got = jax.device_get(R.sketch(state, salt))
         state.delete()
-        passes = R.plan_passes(reg.lower(ops, nq), reg.windows(nq))
+        passes = R.plan_passes(reg.lower(s.ops, nq), reg.windows(nq))
         ref_fn, arrays = R.reference_program(passes, reg.LANE_BITS)
         x = ref_fn(reg.reference_input(factors, num_qubits=nq), arrays)
         want = jax.device_get(R.sketch(x, salt, layout=reg.layout(nq)))
         x.delete()
-    print(f"qbench: set-up {setup_s:.3f} s (lower {lower_s:.3f} s + compile "
-          f"{compile_s - lower_s:.3f} s), "
+    print(f"qbench: set-up {setup_s:.3f} s (lower {s.lower_s:.3f} s + "
+          f"compile {s.compile_s - s.lower_s:.3f} s), "
           f"{apps} applications in {t_end - t_start:.3f} s "
           f"({', '.join(f'{lap:.4f}' for lap in laps)}), check "
           f"{time.perf_counter() - t_check:.3f} s", file=sys.stderr)
@@ -195,9 +237,10 @@ def run_cell(cell: dict, config: dict, traffic: dict, metrics: list,
 
     values = {}
     if trace:
-        ctx = {"trace": summary, "compile_s": compile_s,
-               "state_bytes": 8 << n, "peak": peak,
-               "plan_stats": circuit.plan_stats(density=density)}
+        ctx = {"trace": summary, "compile_s": s.compile_s,
+               "state_bytes": reg.state_bytes(nq), "peak": peak,
+               "plan_stats": s.circuit.plan_stats(density=reg.DENSITY),
+               "program": program_record(s, interpret)}
         for m in metrics:
             v = importlib.import_module(f"qbench.metrics.{m['name']}").read(
                 ctx)

@@ -6,9 +6,11 @@ skipped exchange has no place here.)"""
 import jax.numpy as jnp
 import pytest
 
-from qbench.tests.helpers import SMALL, run_small
+from qbench.tests.helpers import CELLS, run_small
 
-CELLS = sorted(SMALL)
+# a QFT cell that BENCHMARK.json does not hold: a cell made of files alone
+QFT = {"name": "sv30_f32.qft", "config": "sv30_f32", "traffic": "qft",
+       "chips": 1}
 
 
 def _unchanged(compiled):
@@ -28,9 +30,17 @@ def _half(compiled):
 
 
 def _altered(compiled):
-    """One amplitude altered where it is produced."""
-    return lambda s: (lambda out: out.at[:, 3, 5].multiply(-1.0))(
-        compiled(s))
+    """One amplitude altered where it is produced: an ordinary one, the
+    amplitude nearest the median magnitude, negated. (It is picked from
+    the output, since the last input follows the window's application
+    count; a negated amplitude a reads proj_gap 2|a| over the norm.)"""
+    def run(s):
+        out = compiled(s)
+        mag = (out[0] ** 2 + out[1] ** 2).reshape(-1)
+        row, lane = jnp.divmod(jnp.argmin(jnp.abs(mag - jnp.median(mag))),
+                               out.shape[2])
+        return out.at[:, row, lane].multiply(-1.0)
+    return run
 
 
 def _bf16(compiled):
@@ -62,3 +72,9 @@ def test_seed_changes_inputs_not_program():
     b = run_small("sv30_f32.rcs_d20", seed=2**40 + 3)
     assert a["correct"] and b["correct"]
     assert a["check"]["proj_gap"]["value"] != b["check"]["proj_gap"]["value"]
+
+
+@pytest.mark.parametrize("fault", [None, _unchanged, _altered, _bf16])
+def test_cell_from_files_alone(fault):
+    res = run_small(QFT, program_hook=fault, limits={"proj_gap": 1e-3})
+    assert res["correct"] == (fault is None), res["check"]

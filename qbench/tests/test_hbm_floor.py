@@ -55,8 +55,9 @@ def test_traced_run_reports_every_per_layer_metric():
     res = run_small("dm15_f32.noisy_d2", trace=True, seconds=0.1)
     got = set(res["metrics"])
     # the CPU trace has no Pallas kernels, so kernel_ms stays out
-    assert got == {"planned_sweeps", "compile_s", "hbm_floor_share",
-                   "idle_share"}
+    assert got == {"planned_sweeps", "compile_s", "mlir_lower_s",
+                   "hbm_floor_share", "idle_share"}
+    assert res["metrics"]["mlir_lower_s"]["value"] > 0
     assert 0 < res["metrics"]["hbm_floor_share"]["value"] < 100
     assert res["device"]["busy_s"] > 0 and res["device"]["window_s"] > 0
     assert res["breakdown"]["device_ops"] and res["correct"]
