@@ -1297,6 +1297,7 @@ class Circuit:
                 parts = PB.sweep_plan(parts * unroll, n)
             else:
                 unroll = 1
+            PB.record_vmem(items, n, parts, unroll)
         loop_iters = iters // unroll
         seg_cache = {}  # identical-structure segments share one kernel
 

@@ -321,7 +321,8 @@ def max_block_row_bits() -> int:
 
 
 def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
-                 batch: int = 1, attr: Optional[list] = None):
+                 batch: int = 1, attr: Optional[list] = None,
+                 vmem_budget: Optional[int] = None):
     """Split fusion-plan items into kernel segments and XLA passthroughs.
     Returns a list of ("segment", [stages], [op_arrays]) and
     ("xla", item) entries, in program order. `batch` sizes the
@@ -332,7 +333,9 @@ def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
     one tuple of input ITEM indices per emitted part (the durable
     executor's elastic cut-boundary attribution, composed with
     fusion.plan's per-item op attribution — docs/RESILIENCE.md
-    §elastic)."""
+    §elastic). A segment also flushes before a stage that would take its
+    VMEM price past `vmem_budget` (vmem_fits; default
+    VMEM_LIMIT_BYTES)."""
     parts: List = []
     part_src: List[tuple] = []      # item indices per emitted part
     seg_src: List[int] = []         # item indices in the open segment
@@ -352,6 +355,17 @@ def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
         seg_src = []
         scat_bits = set()
         b1_floor = 0
+
+    def push(stage, arr):
+        nonlocal scat_bits, b1_floor
+        if stages and not vmem_fits(stages + [stage], n, vmem_budget,
+                                    batch):
+            flush()
+            scat, floor = stage_requirements([stage])
+            scat_bits, b1_floor = set(scat), floor
+        stages.append(stage)
+        seg_src.append(cur_item)
+        arrays.append(arr)
 
     def emit_xla(it):
         parts.append(("xla", it))
@@ -412,9 +426,8 @@ def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
                     raise ValueError(
                         f"channel qubit {q} does not fit an empty "
                         f"segment under the caller's scatter budget")
-            stages.append(BatchSelStage(q, it.index, it.barrier))
-            seg_src.append(cur_item)
-            arrays.append(np.zeros((batch, 8), dtype=np.float32))
+            push(BatchSelStage(q, it.index, it.barrier),
+                 np.zeros((batch, 8), dtype=np.float32))
             continue
         if isinstance(it, F.BandOp):
             lane_p, row_p = _split_preds(it.preds)
@@ -475,13 +488,11 @@ def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
                 # of the mirror are not — measured 538 ms/application
                 # when applied to a d=8 stage)
                 g = g.T
-            stages.append(MatStage(kind, g.shape[0], real_only, lane_p,
-                                   row_p, bit))
-            seg_src.append(cur_item)
             # keep operator arrays HOST-side (numpy): as closure
             # constants they upload with the program instead of occupying
             # HBM and round-tripping device->host at trace time
-            arrays.append(np.stack([g.real, g.imag]).astype(np.float32))
+            push(MatStage(kind, g.shape[0], real_only, lane_p, row_p, bit),
+                 np.stack([g.real, g.imag]).astype(np.float32))
             continue
         if isinstance(it, F.DiagItem):
             op = it.op
@@ -491,9 +502,7 @@ def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
                 lm = sum(1 << q for q in targets if q < LANE_QUBITS)
                 rm = sum(1 << (q - LANE_QUBITS) for q in targets
                          if q >= LANE_QUBITS)
-                stages.append(ParityStage())
-                seg_src.append(cur_item)
-                arrays.append(np.array(
+                push(ParityStage(), np.array(
                     [[np.cos(half), np.sin(half), lm,
                       rm & 0x7FFF, rm >> 15, 0, 0, 0]], dtype=np.float32))
                 continue
@@ -513,17 +522,15 @@ def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
                         rows.append([ang, lm, rm & 0x7FFF, rm >> 15,
                                      0, 0, 0, 0])
                         forms.append("a" if form == "allones" else "p")
-                    stages.append(MultiPhaseStage(tuple(forms)))
-                    seg_src.append(cur_item)
-                    arrays.append(np.array(rows, dtype=np.float32))
+                    push(MultiPhaseStage(tuple(forms)),
+                         np.array(rows, dtype=np.float32))
                     continue
                 d = np.asarray(op.operand, dtype=np.complex128).reshape(-1)
                 lane_p, row_p = _split_preds(
                     tuple(zip(op.controls, op.cstates or
                               (1,) * len(op.controls))))
-                stages.append(DiagVecStage(targets, lane_p, row_p))
-                seg_src.append(cur_item)
-                arrays.append(np.stack([d.real, d.imag]).astype(np.float32))
+                push(DiagVecStage(targets, lane_p, row_p),
+                     np.stack([d.real, d.imag]).astype(np.float32))
                 continue
             if op.kind == "allones" and isinstance(
                     op.operand, (int, float, complex)):
@@ -539,9 +546,7 @@ def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
                         rm |= 1 << (q - LANE_QUBITS)
                         rw |= s << (q - LANE_QUBITS)
                 t = complex(op.operand)
-                stages.append(PhaseStage())
-                seg_src.append(cur_item)
-                arrays.append(np.array(
+                push(PhaseStage(), np.array(
                     [[t.real, t.imag, lm, lw, rm & 0x7FFF, rm >> 15,
                       rw & 0x7FFF, rw >> 15]], dtype=np.float32))
                 continue
@@ -558,9 +563,7 @@ def segment_plan(items: Sequence, n: int, scatter_max: int = SCATTER_MAX,
                 if stage.sliced_kind == "sub":
                     floor = max(floor, stage.sliced_bit + 1)
                 if reserve(bits=new_scat or frozenset(), floor=floor):
-                    stages.append(stage)
-                    seg_src.append(cur_item)
-                    arrays.append(arr)
+                    push(stage, arr)
                     continue
         flush()
         emit_xla(it)
@@ -733,20 +736,20 @@ def sweep_operand_budget() -> int:
 def sweep_plan(parts, n: int, *, scatter_max: int = SCATTER_MAX,
                row_budget: int = None, max_stages: int = MAX_SWEEP_STAGES,
                operand_bytes: int = None, attr: Optional[list] = None,
-               part_attrs: Optional[Sequence] = None):
+               part_attrs: Optional[Sequence] = None,
+               vmem_budget: Optional[int] = None):
     """Merge consecutive ("segment", stages, arrays) parts of a
     segment_plan (or a concatenation of several applications' plans)
     into maximal single-launch sweeps, preserving program order.
     Returns the same part format, so every downstream consumer
     (compile_segment, _scan_partition, the sharded compilers) is
-    unchanged. `n` is unused by the merge rule itself but kept so the
-    layer sits uniformly between segment_plan(items, n) and the kernel
-    compilers. `attr`, when a list, receives one tuple of attribution
-    entries per OUTPUT part, merged from `part_attrs` (one tuple per
-    input part, e.g. segment_plan's item attribution; defaults to each
-    input part's own index) — the durable elastic layer's cut-boundary
-    bookkeeping (docs/RESILIENCE.md §elastic)."""
-    del n
+    unchanged. A merge must also keep the sweep's VMEM price within
+    `vmem_budget` (vmem_fits; default VMEM_LIMIT_BYTES). `attr`, when a
+    list, receives one tuple of attribution entries per OUTPUT part,
+    merged from `part_attrs` (one tuple per input part, e.g.
+    segment_plan's item attribution; defaults to each input part's own
+    index) — the durable elastic layer's cut-boundary bookkeeping
+    (docs/RESILIENCE.md §elastic)."""
     if row_budget is None:
         row_budget = max_block_row_bits()
     if operand_bytes is None:
@@ -783,17 +786,44 @@ def sweep_plan(parts, n: int, *, scatter_max: int = SCATTER_MAX,
                     and len(u_scat) <= scatter_max
                     and u_floor + len(u_scat) <= row_budget
                     and cur_bytes + nbytes <= operand_bytes):
-                out[-1] = ("segment", prev[1] + stages, prev[2] + arrays)
-                out_attr[-1] = out_attr[-1] + src
-                cur_scat, cur_floor = u_scat, u_floor
-                cur_bytes += nbytes
-                continue
+                if vmem_fits(prev[1] + stages, n, vmem_budget,
+                             _batch_of(prev[1] + stages, prev[2] + arrays)):
+                    out[-1] = ("segment", prev[1] + stages,
+                               prev[2] + arrays)
+                    out_attr[-1] = out_attr[-1] + src
+                    cur_scat, cur_floor = u_scat, u_floor
+                    cur_bytes += nbytes
+                    continue
         out.append(("segment", stages, arrays))
         out_attr.append(src)
         cur_scat, cur_floor, cur_bytes = set(scat), floor, nbytes
     if attr is not None:
         attr.extend(out_attr)
     return out
+
+
+def _batch_of(stages, arrays) -> int:
+    """The batch a part's BatchSelStage placeholders are sized for."""
+    return max((a.shape[0] for st, a in zip(stages, arrays)
+                if isinstance(st, BatchSelStage)), default=1)
+
+
+def record_vmem(items, n: int, parts, unroll: int = 1) -> None:
+    """Into an active profiling recording: `quest.sweep_vmem_cut`, the
+    launches the VMEM price adds to `parts` (the plan of `items` over
+    `unroll` applications, swept if sweep fusion is on), against the
+    same plan unpriced; and `quest.sweep_vmem_peak_mib`, the largest
+    priced launch of `parts`."""
+    if not profiling.active():
+        return
+    free = segment_plan(items, n, vmem_budget=np.inf) * unroll
+    if sweep_enabled():
+        free = sweep_plan(free, n, vmem_budget=np.inf)
+    profiling.count("quest.sweep_vmem_cut", max(0, len(parts) - len(free)))
+    profiling.peak("quest.sweep_vmem_peak_mib", max(
+        (sweep_vmem_bytes(p[1], p[2], n)["total_bytes"]
+         for p in parts if p[0] == "segment"),
+        default=0) / (1 << 20))
 
 
 def sweep_enabled() -> bool:
@@ -916,34 +946,152 @@ def fused_record(parts, swept, n: int) -> dict:
     return rec
 
 
-def sweep_vmem_bytes(stages, arrays, n: int, batch: int = 1) -> dict:
-    """CPU-assertable VMEM residency of ONE compiled sweep launch:
-    slot buffers (the in/out rings of the decoupled pipeline, or the
-    legacy NBUF in-place slots) + whole-array operand residency. The
-    accounting behind the sweep budgets: `total_bytes <= budget_bytes`
-    must hold for every plannable geometry (unit-tested over
-    adversarial geometries in tests/test_sweeps.py), which is what
-    lets sweep_plan merge on byte budgets instead of compiling to
-    find out."""
-    geo = segment_geometry(stages, n)
-    steps = sweep_steps(stages, n, batch)
-    block_bytes = 2 * geo.rows_eff * LANES * 4          # f32 planes
+# ---------------------------------------------------------------------------
+# the VMEM price of a sweep
+# ---------------------------------------------------------------------------
+#
+# A (rows_eff, 128) f32 value is rows_eff / 8 vregs, far more than the
+# register file holds, so Mosaic keeps every block-sized value the stage
+# chain has live in scoped VMEM, beside the slot buffers and the
+# operands. The price counts those values in PLANES, one f32 plane of the
+# block (rows_eff x 128 x 4 bytes): a base for the block as loaded and as
+# stored, the largest working set one stage needs while it runs, and
+# every stage's HELD planes, values that depend only on its operand and
+# the row ids. Mosaic may compute those ahead of the chain and keep them
+# all until their stages run: QFT-30's [scb128, 21 phase groups of 6-10
+# terms, phase] asks 125.58M at 2^12 rows, 55 planes, where 16 groups
+# ask 9 planes. The plane counts are calibrated against the scoped
+# allocation Mosaic reports when compiling for a v5e (docs/KERNELS.md,
+# "VMEM price", has every point); the price reads at or above it on
+# each, and a stage chain whose price passes VMEM_LIMIT_BYTES is cut.
+
+CHAIN_BASE_PLANES = 4.0   # the block's re/im as loaded and as stored
+
+
+def _stage_planes(st) -> Tuple[float, float]:
+    """(work, held) planes of one stage: `work` is live while it runs,
+    `held` depends only on its operand and the row ids."""
+    mask = 1.0 if (getattr(st, "lane_preds", ()) or
+                   getattr(st, "row_preds", ())) else 0.0
+    if isinstance(st, MultiPhaseStage):
+        m = len(st.forms)
+        if multiphase_trigfree(st):
+            # the terms' bits and the select tree's widest level; the
+            # factor it selects is held
+            return m + (1 << m) + 2.0, 2.0
+        # one term's mask and angle, the products; the angle sum and its
+        # cos/sin are held
+        return 5.0, 3.0
+    if isinstance(st, PhaseStage):
+        return 4.0, 0.5          # the products; the match mask
+    if isinstance(st, ParityStage):
+        return 4.0, 1.0          # the products; the signed sine
+    if isinstance(st, DiagVecStage):
+        return 5.0 + mask, 2.0   # the select, products; the factor
+    # MatStage, PairStage, BatchSelStage: the Gauss products, the
+    # contraction's frame in and out, and the mask of a controlled one
+    return 8.0 + mask, 0.0
+
+
+BUTTERFLY_PAIR_PLANES = 48.0  # two unmasked 'sc' butterflies on one
+# bit back to back (a merge across applications of one circuit; fusion
+# composes them within one): Mosaic asks 52 planes for the pair, at 2^10
+# and at 2^11 rows, where either alone asks 8
+
+
+def _unmasked_butterfly(st):
+    return (isinstance(st, MatStage) and st.kind == "sc"
+            and not st.lane_preds and not st.row_preds)
+
+
+def chain_planes(stages) -> float:
+    """Block planes the stage chain keeps live: the base, the largest
+    working set of one stage and every stage's held planes."""
+    planes = [_stage_planes(st) for st in stages]
+    pair = any(_unmasked_butterfly(a) and _unmasked_butterfly(b)
+               and a.bit == b.bit for a, b in zip(stages, stages[1:]))
+    return (CHAIN_BASE_PLANES + max((w for w, _ in planes), default=0.0)
+            + sum(h for _, h in planes)
+            + (BUTTERFLY_PAIR_PLANES if pair else 0.0))
+
+
+def _vmem_array_bytes(shape) -> int:
+    """Bytes of an f32 array in VMEM, its last two dims padded to the
+    (8, 128) tile."""
+    *lead, a, b = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    return (int(np.prod(lead, dtype=np.int64)) * (-(-a // 8) * 8)
+            * (-(-b // LANES) * LANES) * 4)
+
+
+def _operand_shape(st, batch: int):
+    """The shape of a stage's operand (see the stage dataclasses)."""
+    if isinstance(st, MatStage):
+        return (2, st.dim, st.dim)
+    if isinstance(st, PairStage):
+        return (2, 4, st.op_dim, st.op_dim)
+    if isinstance(st, MultiPhaseStage):
+        return (len(st.forms), 8)
+    if isinstance(st, DiagVecStage):
+        return (2, 1 << len(st.targets))
+    if isinstance(st, BatchSelStage):
+        return (batch, 8)
+    return (1, 8)
+
+
+def _slot_count(steps: int) -> int:
     if decoupled_active():
-        slots = (min(PIPELINE_IN_SLOTS, steps)
-                 + min(PIPELINE_OUT_SLOTS, steps))
-    elif _driver_override() == "pipelined":
-        slots = min(NBUF, steps)
-    else:
-        slots = 2                # the grid driver's double buffering
-    operand_bytes = sum(int(a.nbytes) for a in arrays)
+        return (min(PIPELINE_IN_SLOTS, steps)
+                + min(PIPELINE_OUT_SLOTS, steps))
+    if _driver_override() == "pipelined":
+        return min(NBUF, steps)
+    return 2                     # the grid driver's double buffering
+
+
+def _vmem_price(stages, geo: "_Geometry", batch: int = 1) -> dict:
+    steps = batch
+    for (_, w) in geo.gaps:
+        steps <<= w
+    plane_bytes = geo.rows_eff * LANES * 4
+    slots = _slot_count(steps)
+    operand_bytes = sum(_vmem_array_bytes(_operand_shape(st, batch))
+                        for st in stages)
+    chain_bytes = int(chain_planes(stages) * plane_bytes)
     return {
-        "block_bytes": block_bytes,
+        "block_bytes": 2 * plane_bytes,                  # f32 planes
         "slots": slots,
-        "slot_bytes": slots * block_bytes,
+        "slot_bytes": slots * 2 * plane_bytes,
         "operand_bytes": operand_bytes,
-        "total_bytes": slots * block_bytes + operand_bytes,
+        "chain_bytes": chain_bytes,
+        "total_bytes": slots * 2 * plane_bytes + operand_bytes + chain_bytes,
         "budget_bytes": VMEM_LIMIT_BYTES,
     }
+
+
+def vmem_fits(stages, n: int, budget: int = None, batch: int = 1) -> bool:
+    """Whether one launch of `stages` prices within `budget` (default
+    VMEM_LIMIT_BYTES) at the rows a block holds by default: the merge and
+    flush rule of sweep_plan and segment_plan. A chain that does not fit
+    is cut rather than run in smaller blocks: on a v5e, QFT-30's former
+    sweep 2 took 927.7 ms as two sweeps of 2^12 rows and 1,627.4 ms as
+    one of 2^11, where Mosaic keeps its wide phase groups' factor planes
+    (docs/KERNELS.md, "VMEM price")."""
+    price = _vmem_price(stages, segment_geometry(stages, n), batch)
+    return price["total_bytes"] <= (VMEM_LIMIT_BYTES if budget is None
+                                    else budget)
+
+
+def sweep_vmem_bytes(stages, arrays, n: int,
+                     rows_eff_bits: Optional[int] = None) -> dict:
+    """CPU-assertable VMEM price of ONE compiled sweep launch, `stages`
+    with their operand `arrays`, at the geometry compile_segment gives it
+    (or at `rows_eff_bits`): slot buffers (the in/out rings of the
+    decoupled pipeline, or the legacy NBUF in-place slots), operands
+    padded to the (8, 128) tile, and the stage chain's block planes
+    (chain_planes). `total_bytes <= budget_bytes` holds for every launch
+    the planner makes (tests/test_sweeps.py), which is what lets
+    sweep_plan merge on byte budgets instead of compiling to find out."""
+    return _vmem_price(stages, segment_geometry(stages, n, rows_eff_bits),
+                       _batch_of(stages, arrays))
 
 
 # ---------------------------------------------------------------------------
@@ -2095,29 +2243,11 @@ def compile_segment(stages: Sequence, n: int,
                                    geo=geo, batched=batched)
         in_specs = [pl.BlockSpec(full_block, index_map)]
         for st in stages:
-            if isinstance(st, PairStage):
-                d = st.op_dim
-                in_specs.append(
-                    pl.BlockSpec((2, 4, d, d), lambda *ids: (0, 0, 0, 0)))
-            elif isinstance(st, MatStage):
-                d = st.dim
-                in_specs.append(
-                    pl.BlockSpec((2, d, d), lambda *ids: (0, 0, 0)))
-            elif isinstance(st, BatchSelStage):
-                # the whole per-state table rides resident (batch x 32
-                # bytes); the kernel row-selects by the batch grid id
-                in_specs.append(
-                    pl.BlockSpec((nbatch, 8), lambda *ids: (0, 0)))
-            elif isinstance(st, MultiPhaseStage):
-                in_specs.append(
-                    pl.BlockSpec((len(st.forms), 8), lambda *ids: (0, 0)))
-            elif isinstance(st, DiagVecStage):
-                k = len(st.targets)
-                in_specs.append(
-                    pl.BlockSpec((2, 1 << k), lambda *ids: (0, 0)))
-            else:                # PhaseStage / ParityStage packed
-                # values + predicate masks, (1, 8) — see the dataclasses
-                in_specs.append(pl.BlockSpec((1, 8), lambda *ids: (0, 0)))
+            # each operand rides whole (a BatchSelStage's per-state
+            # table too: the kernel row-selects by the batch grid id)
+            shape = _operand_shape(st, nbatch)
+            in_specs.append(pl.BlockSpec(
+                shape, lambda *ids, rank=len(shape): (0,) * rank))
         fn = pl.pallas_call(
             kernel,
             grid=full_grid,

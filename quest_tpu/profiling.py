@@ -131,16 +131,17 @@ class Recording:
     """Spans, counters and compile phases collected while active.
 
     `spans` are the annotate() regions that closed; `counts` hold count()
-    and one per compile-phase or cache event; `seconds(phase)` is the
-    wall time covered by a phase's events (a union: a jit traced inside
-    another's trace is not counted twice)."""
+    and one per compile-phase or cache event, and peak()'s largest
+    values; `seconds(phase)` is the wall time covered by a phase's events
+    (a union: a jit traced inside another's trace is not counted
+    twice)."""
 
     _GUARDED_BY = {"_lock": ("counts", "_phases"),
                    "<owner-thread>": ("anchor_ns",)}
 
     def __init__(self):
         self.spans: List[Span] = []
-        self.counts: Dict[str, int] = {}
+        self.counts: Dict[str, float] = {}
         self._phases: Dict[str, List[Tuple[float, float]]] = {}
         self._lock = threading.Lock()
         self._tls = threading.local()
@@ -157,6 +158,10 @@ class Recording:
     def _count(self, name: str, n: int = 1) -> None:
         with self._lock:
             self.counts[name] = self.counts.get(name, 0) + n
+
+    def _peak(self, name: str, value: float) -> None:
+        with self._lock:
+            self.counts[name] = max(self.counts.get(name, value), value)
 
     def _on_compile(self, name: str, start: float, end: float) -> None:
         with self._lock:
@@ -238,6 +243,18 @@ def count(name: str, n: int = 1) -> None:
     """Add n to the active recording's counter `name`."""
     if _ACTIVE is not None:
         _ACTIVE._count(name, n)
+
+
+def peak(name: str, value: float) -> None:
+    """Raise the active recording's `name` to `value` if it is below."""
+    if _ACTIVE is not None:
+        _ACTIVE._peak(name, value)
+
+
+def active() -> bool:
+    """Whether a recording is collecting (count and peak are no-ops
+    otherwise, so a caller may skip the work of a value)."""
+    return _ACTIVE is not None
 
 
 # ---------------------------------------------------------------------------

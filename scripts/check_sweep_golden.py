@@ -32,7 +32,8 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-QFT30_GOLDEN_SWEEPS = 6
+QFT30_GOLDEN_SWEEPS = 8  # 6 until the VMEM price held a sweep to 10 wide
+# phase groups (docs/KERNELS.md, "VMEM price")
 CHAIN30_GOLDEN_SWEEPS = 1
 
 # bench.py keys the trajectory parser needs for the round's deltas

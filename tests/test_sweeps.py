@@ -23,7 +23,7 @@ import pytest
 import jax.numpy as jnp
 
 import bench
-from quest_tpu.circuit import Circuit, GateOp, qft_circuit
+from quest_tpu.circuit import Circuit, GateOp, qft_circuit, random_circuit
 from quest_tpu.ops import fusion as F
 from quest_tpu.ops import pallas_band as PB
 from tests import oracle
@@ -49,8 +49,9 @@ def plan_parts(c: Circuit, n: int = N, density: bool = False):
 # goldens: the benchmark circuits' hbm_sweeps (acceptance metric)
 # ---------------------------------------------------------------------------
 
-QFT30_GOLDEN_SWEEPS = 6      # committed golden (scripts/check_sweep_golden.py
-CHAIN30_GOLDEN_SWEEPS = 1    # runs the same assertions in CI)
+QFT30_GOLDEN_SWEEPS = 8      # committed golden (scripts/check_sweep_golden.py
+CHAIN30_GOLDEN_SWEEPS = 1    # runs the same assertions in CI); QFT-30's
+# sweeps 7 and 8 are the VMEM price's: at most 10 wide phase groups a sweep
 
 
 def test_qft30_golden_hbm_sweeps():
@@ -70,6 +71,163 @@ def test_chain30_golden_hbm_sweeps():
     assert rec["hbm_sweeps"] == CHAIN30_GOLDEN_SWEEPS, rec
     assert rec["stages"] == bench.GATES_PER_STEP
     assert 2 * rec["hbm_sweeps"] <= rec["stages"], rec
+
+
+# ---------------------------------------------------------------------------
+# the VMEM price (pallas_band.sweep_vmem_bytes, docs/KERNELS.md)
+# ---------------------------------------------------------------------------
+
+# Mosaic's scoped allocation for QFT-30's sweep 2 as planned before the
+# price, [scb128, 21 phase groups, phase] at 2^12 rows, compiled for a v5e
+QFT30_FORMER_SWEEP2_MOSAIC = 125.58 * (1 << 20)
+
+
+def _swept(c: Circuit, n: int, density: bool = False, budget=None):
+    items = F.plan(c._planned_flat(n, density), n, bands=PB.plan_bands(n))
+    return PB.sweep_plan(PB.segment_plan(items, n, vmem_budget=budget), n,
+                         vmem_budget=budget)
+
+
+def qft30_former_sweep2():
+    """QFT-30's sweep 2 as planned before the price: the planner with no
+    VMEM budget."""
+    return _swept(qft_circuit(30), 30, budget=1 << 62)[2]
+
+
+def test_vmem_price_covers_mosaic_on_qft30_former_sweep2():
+    _, stages, arrays = qft30_former_sweep2()
+    geo = PB.segment_geometry(stages, 30, PB.ROWS_EFF_BITS)
+    assert PB.kernel_name(stages, geo).startswith(
+        "quest_seg_mat1_multiphase21_phase1_r5s7_"), stages
+    rec = PB.sweep_vmem_bytes(stages, arrays, 30,
+                              rows_eff_bits=PB.ROWS_EFF_BITS)
+    assert rec["total_bytes"] >= QFT30_FORMER_SWEEP2_MOSAIC, rec
+    assert rec["total_bytes"] > PB.VMEM_LIMIT_BYTES
+
+
+def test_qft30_sweeps_price_within_limit():
+    """Every sweep the planner makes of QFT-30 prices within the scoped
+    limit at the rows it compiles with: a long chain of wide phase
+    groups is cut, at most 10 of them a sweep."""
+    parts = _swept(qft_circuit(30), 30)
+    for part in parts:
+        assert part[0] == "segment"
+        rec = PB.sweep_vmem_bytes(part[1], part[2], 30)
+        assert rec["total_bytes"] <= PB.VMEM_LIMIT_BYTES, rec
+    trig = [sum(isinstance(st, PB.MultiPhaseStage)
+                and not PB.multiphase_trigfree(st) for st in p[1])
+            for p in parts]
+    assert sum(trig) == 48 and max(trig) <= 10, trig
+    assert sum(len(p[1]) for p in parts) == 98
+
+
+def test_adjacent_butterflies_across_applications_priced():
+    """Two applications of a lone high-qubit gate would merge into two
+    unmasked butterflies on one bit, which Mosaic holds in 52 planes (48
+    more than either alone, docs/KERNELS.md): past the scoped limit at
+    2^12 rows, so they stay two sweeps; at 16 qubits they merge."""
+    c = Circuit(30)
+    c.h(20)
+    parts = PB.sweep_plan(plan_parts(c, 30) * 2, 30)
+    assert [[(st.kind, st.bit) for st in p[1]] for p in parts] == \
+        [[("sc", 13)]] * 2
+    stages = parts[0][1] + parts[1][1]
+    assert PB.chain_planes(stages) == PB.chain_planes(stages[:1]) + \
+        PB.BUTTERFLY_PAIR_PLANES
+    assert not PB.vmem_fits(stages, 30)
+    c = Circuit(16)
+    c.h(15)
+    (part,) = PB.sweep_plan(plan_parts(c, 16) * 2, 16)
+    assert [(st.kind, st.bit) for st in part[1]] == [("sc", 8)] * 2
+
+
+def noisy_d2_circuit(n: int = 15) -> Circuit:
+    """qbench/traffic/noisy_d2.json's circuit: random_circuit's draws for
+    two brick layers, a depolarising channel after every gate (on both
+    qubits of an entangler) and damping at each layer's end."""
+    rng = np.random.default_rng(7)
+    c = Circuit(n)
+    for d in range(2):
+        for q in range(n):
+            angle = float(rng.uniform(0, 2 * np.pi))
+            (c.rx, c.ry, c.rz)[int(rng.integers(0, 3))](q, angle)
+            c.depolarising(q, 0.0016)
+        for q in range(d % 2, n - 1, 2):
+            c.cz(q, q + 1)
+            c.depolarising(q, 0.0062)
+            c.depolarising(q + 1, 0.0062)
+        for q in range(n):
+            c.damping(q, 0.002)
+    return c
+
+
+# The benchmark cells' sweeps as planned before the VMEM price: each
+# kernel name's stage kinds, counts and block geometry (the digest after
+# it covers the stage tuples).
+CELL_PLANS = {
+    "sv30_f32.rcs_d20": (
+        lambda: _swept(random_circuit(30, 20, seed=7, entangler="cz"), 30),
+        ["mat2_parity3_r12s0", "mat1_r5s7",
+         "mat2_multiphase1_parity2_phase1_r5s7", "mat1_r12s0",
+         "mat1_multiphase1_parity1_r5s7", "mat2_r10s2",
+         "mat1_multiphase1_r5s7", "mat1_r5s7", "mat1_multiphase1_r5s7",
+         "mat1_r12s0", "mat1_multiphase1_r5s7", "mat3_r10s2", "mat1_r5s7",
+         "mat1_multiphase1_r5s7", "mat1_multiphase1_r5s7", "mat1_r5s7",
+         "mat3_r10s2", "mat2_multiphase2_r5s7", "mat1_r5s7",
+         "mat3_multiphase1_r10s2", "mat1_multiphase1_r5s7", "mat1_r5s7",
+         "mat2_r10s2", "mat2_multiphase1_r5s7", "mat1_r12s0",
+         "mat1_multiphase1_r5s7", "mat3_r10s2", "mat1_multiphase1_r5s7",
+         "mat1_multiphase1_r5s7", "mat2_r5s7", "mat1_r12s0", "mat1_r5s7",
+         "mat2_multiphase2_r10s2", "mat1_r5s7", "mat4_multiphase1_r10s2",
+         "mat1_multiphase1_r5s7", "mat1_multiphase1_r12s0", "mat1_r5s7"],
+        "cb9948cebe39426f88217bcfc11f8b47469bd6b5e8bde9fff9d8d51fc2dd8822"),
+    "dm15_f32.noisy_d2": (
+        lambda: _swept(noisy_d2_circuit(), 30, density=True),
+        ["mat2_pair6_parity5_r5s7", "mat1_pair1_r11s1",
+         "mat1_pair6_parity1_phase1_r7s6", "pair5_parity1_phase3_r7s6",
+         "pair6_phase5_r7s6", "pair6_phase3_r7s6", "pair6_r6s6",
+         "pair6_r7s6", "mat1_pair2_r7s3", "mat1_pair6_r5s7",
+         "mat1_pair1_parity1_phase1_r5s7", "mat1_pair6_parity2_r7s6",
+         "pair5_parity1_phase5_r7s6", "pair6_phase4_r7s6",
+         "pair5_phase3_r7s6", "pair7_r5s7", "pair6_r7s6", "pair2_r7s3"],
+        "263e19fe2f669c94378f118bf62cf91f65277492d719ad1e1a8e39b174adba86"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PLANS))
+def test_benchmark_cell_plans_unchanged_by_vmem_price(cell):
+    """The price leaves the short chains of the RCS and noisy cells as
+    they were: the same sweeps, stage kinds and kernel names."""
+    import hashlib
+    import re
+    plan, kinds, digest = CELL_PLANS[cell]
+    parts = plan()
+    names = [PB.kernel_name(p[1], PB.segment_geometry(p[1], 30))
+             for p in parts]
+    assert [re.sub(r"^quest_seg_|_[0-9a-f]{8}$", "", x)
+            for x in names] == kinds
+    assert hashlib.sha256("\n".join(names).encode()).hexdigest() == digest
+    for p in parts:
+        assert PB.sweep_vmem_bytes(p[1], p[2], 30)["total_bytes"] <= \
+            PB.VMEM_LIMIT_BYTES
+
+
+def test_vmem_cut_counted_in_plan_span():
+    """quest.sweep_vmem_cut counts the merges only the price refused and
+    quest.sweep_vmem_peak_mib the largest priced sweep, both inside the
+    quest.plan span of compiled_fused."""
+    from quest_tpu import profiling
+    c = qft_circuit(30)
+    with profiling.recording() as rec:
+        c.compiled_fused(30, False, donate=True)
+    assert rec.counts["quest.sweep_vmem_cut"] == 2
+    peak = max(PB.sweep_vmem_bytes(p[1], p[2], 30)["total_bytes"]
+               for p in _swept(c, 30))
+    assert rec.counts["quest.sweep_vmem_peak_mib"] == peak / (1 << 20)
+    assert rec.counts["quest.multiphase_trig"] == 48
+    with profiling.recording() as rec:
+        random_circuit(12, 4, seed=1).compiled_fused(12, False)
+    assert rec.counts["quest.sweep_vmem_cut"] == 0
 
 
 def test_cross_iteration_sweeps_collapse_bench_dispatch():
