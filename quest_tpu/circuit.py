@@ -456,7 +456,7 @@ def _estimate_ms(parts, n, model=None):
                     + (model["b1_extra"] if st.kind == "b1" else 0.0))
         if isinstance(st, PB.PairStage):
             return model["pair"]
-        if isinstance(st, PB.MultiPhaseStage):
+        if isinstance(st, (PB.MultiPhaseStage, PB.LookupPhaseStage)):
             # PROJECTED from the measured per-phase constant, not
             # calibrated: the v5e measured 33.7 ms (trig path) and
             # 15.2 ms (trig-free) for m=2 behind an scb128 stage, against
@@ -1298,6 +1298,7 @@ class Circuit:
             else:
                 unroll = 1
             PB.record_vmem(items, n, parts, unroll)
+            parts = PB.lookup_plan(parts, n)
         loop_iters = iters // unroll
         seg_cache = {}  # identical-structure segments share one kernel
 
@@ -1655,6 +1656,7 @@ class Circuit:
             lines.append(
                 f"  sweep fusion: OFF (QUEST_SWEEP_FUSION=0); on, it "
                 f"would merge {nseg} segment(s) into {nsw} sweep(s)")
+        parts = PB.lookup_plan(parts, n)
         kernels = set()
         passes = 0
         for i, part in enumerate(parts):

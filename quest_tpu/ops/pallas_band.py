@@ -106,6 +106,12 @@ MULTIPHASE_TRIGFREE_MAX = 3  # MultiPhaseStage terms up to which the
 # 30q behind an scb128 stage (docs/KERNELS.md): at m = 3 the select tree
 # is 15 ms (allones) and 9 ms (parity) cheaper; at m = 4 parity groups
 # are 8 ms dearer, and at m = 8 its temporaries overflow scoped VMEM
+MULTIPHASE_LOOKUP_MAX_BITS = 4  # block-local row bits up to which a wider
+# group takes its phases from a per-step table (LookupPhaseStage) instead
+# of cos/sin of a per-element angle sum. Measured on v5e at 30q behind
+# an scb128 stage (docs/KERNELS.md, "block-local lookup"): at k = 4 the
+# lookup costs 32-36 ms against trig's 50-57, at k = 5 it ties (55.5
+# against 56.6 ms at m = 10)
 
 
 def plan_bands(n: int) -> List[Tuple[int, int]]:
@@ -202,6 +208,23 @@ class MultiPhaseStage:
     split at bit 15 so each half is exact in f32); `forms` carries the
     static per-row interpretation: 'a' = allones, 'p' = parity."""
     forms: Tuple[str, ...]
+
+
+@dataclasses.dataclass(frozen=True)
+class LookupPhaseStage:
+    """A wide MultiPhaseStage whose terms read only `k` row bits that vary
+    inside the block (lookup_plan decides it from the operand under the
+    sweep's geometry): every other row bit is one value for the whole
+    grid step. Each step builds a (2^k, 128) table of the group's phase
+    by local-bit pattern and lane, takes cos/sin of the table alone, and
+    selects each row's table row by its k local bits (2^k - 1 selects a
+    plane). The (m, 8) operand rows are [angle, lane_mask, const_mask_lo,
+    const_mask_hi, local_mask, local_bit, 0, 0]: the term's row bits
+    split into the block-constant ones (split at bit 15) and a mask over
+    the k pattern bits; `local_bit` of row j < k is the GLOBAL row bit
+    that pattern bit j reads (k < m always holds)."""
+    forms: Tuple[str, ...]
+    k: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -836,11 +859,69 @@ def sweep_enabled() -> bool:
 
 
 def maybe_sweep(parts, n: int):
-    """sweep_plan honoring the QUEST_SWEEP_FUSION knob — the engines'
-    entry point (stats consumers call sweep_plan/sweep_stats)."""
-    if not sweep_enabled():
-        return list(parts)
-    return sweep_plan(parts, n)
+    """sweep_plan honoring the QUEST_SWEEP_FUSION knob, then lookup_plan
+    — the engines' entry point (stats consumers call
+    sweep_plan/sweep_stats)."""
+    if sweep_enabled():
+        parts = sweep_plan(parts, n)
+    return lookup_plan(parts, n)
+
+
+def lookup_plan(parts, n: int, rows_eff_bits: Optional[int] = None):
+    """Re-encode each wide MultiPhaseStage whose terms read at most
+    MULTIPHASE_LOOKUP_MAX_BITS row bits that vary inside its launch's
+    block as a LookupPhaseStage: a pass over a finished (swept) part list,
+    since the block geometry is the whole launch's. Sweeps, stage counts
+    and block geometry are unchanged: the planner priced the trig form,
+    and a group keeps it where its lookup would take the launch's price
+    past the scoped limit. Decided from the operand's masks, so a group
+    of one shape keeps sharing one kernel wherever its bits lie."""
+    out = []
+    for part in parts:
+        if part[0] == "segment" and any(map(_trig_group, part[1])):
+            geo = segment_geometry(part[1], n, rows_eff_bits)
+            local = ((1 << geo.inner_bits) - 1) | sum(1 << s for s in geo.scat)
+            stages, arrays = list(part[1]), list(part[2])
+            for i, st in enumerate(part[1]):
+                if _trig_group(st):
+                    st, arr = _lookup_stage(st, arrays[i], local)
+                    trial = stages[:i] + [st] + stages[i + 1:]
+                    # a lookup's select tree can outgrow the widest stage
+                    if _vmem_price(trial, geo, _batch_of(stages, arrays))[
+                            "total_bytes"] <= VMEM_LIMIT_BYTES:
+                        stages[i], arrays[i] = st, arr
+            part = ("segment", stages, arrays)
+        out.append(part)
+    return out
+
+
+def _trig_group(st) -> bool:
+    return isinstance(st, MultiPhaseStage) and not multiphase_trigfree(st)
+
+
+def _lookup_stage(st: MultiPhaseStage, arr, local: int):
+    """(LookupPhaseStage, operand) for a trig group whose row masks touch
+    few bits of `local` (the block's row-bit mask); (st, arr) unchanged
+    otherwise, and where the table would hold as many rows as the block:
+    its cos/sin and per-term sums then cost what the trig path's do, and
+    the select tree comes on top."""
+    rm = (arr[:, 2].astype(np.int64)
+          | (arr[:, 3].astype(np.int64) << 15))
+    touched = int(np.bitwise_or.reduce(rm & local))
+    bits = [b for b in range(touched.bit_length()) if (touched >> b) & 1]
+    k = len(bits)
+    if (k > MULTIPHASE_LOOKUP_MAX_BITS or k >= len(st.forms)
+            or k >= local.bit_count()):
+        return st, arr
+    const = rm & ~local
+    out = np.zeros_like(arr)
+    out[:, :2] = arr[:, :2]
+    out[:, 2] = const & 0x7FFF
+    out[:, 3] = const >> 15
+    out[:, 4] = [sum(1 << j for j, b in enumerate(bits) if (r >> b) & 1)
+                 for r in rm]
+    out[:k, 5] = bits
+    return LookupPhaseStage(st.forms, k), out
 
 
 def sweep_stats(parts) -> dict:
@@ -982,6 +1063,10 @@ def _stage_planes(st) -> Tuple[float, float]:
         # one term's mask and angle, the products; the angle sum and its
         # cos/sin are held
         return 5.0, 3.0
+    if isinstance(st, LookupPhaseStage):
+        # the select tree's widest level and the products (its pattern
+        # bits are row columns); the selected factor is held
+        return (1 << st.k) + 2.0, 2.0
     if isinstance(st, PhaseStage):
         return 4.0, 0.5          # the products; the match mask
     if isinstance(st, ParityStage):
@@ -1029,7 +1114,7 @@ def _operand_shape(st, batch: int):
         return (2, st.dim, st.dim)
     if isinstance(st, PairStage):
         return (2, 4, st.op_dim, st.op_dim)
-    if isinstance(st, MultiPhaseStage):
+    if isinstance(st, (MultiPhaseStage, LookupPhaseStage)):
         return (len(st.forms), 8)
     if isinstance(st, DiagVecStage):
         return (2, 1 << len(st.targets))
@@ -1504,6 +1589,43 @@ def _apply_multiphase_stage(re, im, st: MultiPhaseStage, gref, row_ids):
     return nre, nim
 
 
+def _apply_lookup_stage(re, im, st: LookupPhaseStage, gref, row_ids):
+    # operand rows: [angle, lane_mask, const_mask_lo, const_mask_hi,
+    # local_mask, local_bit, 0, 0] (LookupPhaseStage). The table's
+    # entries are the per-element angle sums of the trig path, formed
+    # once per (pattern, lane) instead of once per element.
+    g = gref[...]
+    shape = (max(8, 1 << st.k), LANES)
+    pat = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    base = row_ids[0:1, :]     # the block's first row: its local bits are 0
+    tot = None
+    for r, form in enumerate(st.forms):
+        lm = g[r, 1].astype(jnp.int32)
+        cm = _row_halves(g[r, 2], g[r, 3])
+        pm = g[r, 4].astype(jnp.int32)
+        if form == "a":
+            t = (((lane & lm) == lm) & ((base & cm) == cm)
+                 & ((pat & pm) == pm))
+            contrib = jnp.where(t, g[r, 0], 0.0)
+        else:
+            t = (_xor_fold(lane & lm, 4) ^ _xor_fold(base & cm, 16)
+                 ^ _xor_fold(pat & pm, 4))
+            contrib = g[r, 0] * (1.0 - 2.0 * t.astype(jnp.float32))
+        tot = contrib if tot is None else tot + contrib
+    cos_t, sin_t = jnp.cos(tot), jnp.sin(tot)
+    table = [(cos_t[p:p + 1], sin_t[p:p + 1]) for p in range(1 << st.k)]
+    for j in reversed(range(st.k)):
+        bit = ((row_ids >> g[j, 5].astype(jnp.int32)) & 1) != 0
+        half = len(table) // 2
+        table = [(jnp.where(bit, hi[0], lo[0]), jnp.where(bit, hi[1], lo[1]))
+                 for lo, hi in zip(table[:half], table[half:])]
+    (fre, fim), = table
+    nre = re * fre - im * fim
+    nim = re * fim + im * fre
+    return nre, nim
+
+
 def _bit_of(q, row_ids):
     """(broadcastable) value of bit `q` of each amplitude's global index."""
     if q < LANE_QUBITS:
@@ -1733,6 +1855,8 @@ def _apply_stages(re, im, stages, mat_refs, geo: _Geometry, row_ids,
             re, im = _apply_phase_stage(re, im, st, ref, row_ids)
         elif isinstance(st, MultiPhaseStage):
             re, im = _apply_multiphase_stage(re, im, st, ref, row_ids)
+        elif isinstance(st, LookupPhaseStage):
+            re, im = _apply_lookup_stage(re, im, st, ref, row_ids)
         elif isinstance(st, DiagVecStage):
             re, im = _apply_diagvec_stage(re, im, st, ref, row_ids)
         else:
@@ -2293,13 +2417,16 @@ def compile_segment_cached(cache: dict, stages: Sequence, n: int,
     differ only in values — e.g. RCS layers with different angles —
     share one compiled kernel. The ONE place the cache key lives
     (batch is part of it: a bucket's kernels are shaped for it).
-    Counts each call's MultiPhaseStages by path, hit or miss, into the
-    active recording: `quest.multiphase_trigfree` / `quest.multiphase_trig`."""
+    Counts each call's phase groups by path, hit or miss, into the
+    active recording: `quest.multiphase_trigfree` /
+    `quest.multiphase_lookup` / `quest.multiphase_trig`."""
     for st in stages:
         if isinstance(st, MultiPhaseStage):
             profiling.count("quest.multiphase_trigfree"
                             if multiphase_trigfree(st)
                             else "quest.multiphase_trig")
+        elif isinstance(st, LookupPhaseStage):
+            profiling.count("quest.multiphase_lookup")
     key = (tuple(stages), n, interpret, batch)
     fn = cache.get(key)
     if fn is None:

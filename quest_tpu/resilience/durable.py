@@ -246,6 +246,7 @@ def _build_steps(circuit, n: int, density: bool, engine: str,
                                   part_attrs=seg_attr)
         else:
             parts, part_attr = list(seg_parts), list(seg_attr)
+        parts = PB.lookup_plan(parts, n)
         # per-STEP flat-op attribution: parts index items, items index
         # flat ops
         step_attr = [frozenset().union(*(item_attr[i] for i in pa))

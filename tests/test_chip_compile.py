@@ -160,6 +160,38 @@ def test_qft30_former_sweep2_kernels_fit_their_vmem_price(one_chip,
         list(pool.map(lambda fn: _compile(fn, state), fns))
 
 
+def test_qft30_lookup_kernels_fit_their_vmem_price(one_chip, monkeypatch):
+    """Each QFT-30 kernel that holds a block-local lookup stage, as the
+    engine compiles it (lookup_plan over the swept plan), compiles with
+    its scoped limit set to its price: the lookup's price reads at or
+    above Mosaic's allocation. The compiles run side by side."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from quest_tpu.circuit import qft_circuit
+    from quest_tpu.ops import fusion as F
+    from quest_tpu.ops import pallas_band as PB
+    from quest_tpu.state import fused_state_shape
+    n = 30
+    items = F.plan(qft_circuit(n)._planned_flat(n, False), n,
+                   bands=PB.plan_bands(n))
+    fns = []
+    for _, stages, arrays in PB.lookup_plan(
+            PB.sweep_plan(PB.segment_plan(items, n), n), n):
+        if any(isinstance(st, PB.LookupPhaseStage) for st in stages):
+            price = PB.sweep_vmem_bytes(stages, arrays, n)["total_bytes"]
+            assert price <= PB.VMEM_LIMIT_BYTES
+            monkeypatch.setattr(PB, "VMEM_LIMIT_BYTES", price)
+            seg = PB.compile_segment(stages, n)
+            monkeypatch.undo()
+            fns.append(jax.jit(lambda a, seg=seg, arrays=arrays:
+                               seg(a, arrays), donate_argnums=0))
+    assert len(fns) == 5
+    state = jax.ShapeDtypeStruct(fused_state_shape(n), jnp.float32,
+                                 sharding=one_chip)
+    with ThreadPoolExecutor(len(fns)) as pool:
+        list(pool.map(lambda fn: _compile(fn, state), fns))
+
+
 def test_density_15q(one_chip):
     from quest_tpu.circuit import Circuit
     from quest_tpu.state import fused_state_shape

@@ -808,15 +808,16 @@ def _mp_qubits(rng, masks):
 
 def _mp_want(vec, forms, rows):
     """The group's phase per basis index, from the operand rows alone."""
-    idx = np.arange(1 << _MP_N)
-    phase = np.zeros(1 << _MP_N)
+    n = vec.size.bit_length() - 1
+    idx = np.arange(1 << n)
+    phase = np.zeros(1 << n)
     for form, (ang, lm, rlo, rhi) in zip(forms, rows):
         mask = int(lm) | ((int(rlo) | (int(rhi) << 15)) << PB.LANE_QUBITS)
         if form == "a":
             phase += np.where(idx & mask == mask, ang, 0.0)
         else:
             par = np.zeros_like(idx)
-            for b in range(_MP_N):
+            for b in range(n):
                 par ^= ((idx & mask) >> b) & 1
             phase += ang * (1 - 2 * par)
     return vec * np.exp(1j * phase)
@@ -861,8 +862,8 @@ def test_multiphase_stage_matches_oracle(form, masks, angle, m):
 
 
 def test_multiphase_counters_name_each_path():
-    """compile_segment_cached counts each MultiPhaseStage by path on a
-    hit and a miss alike; an RCS plan's CZ pairs all take the trig-free
+    """compile_segment_cached counts each phase group by path on a hit
+    and a miss alike; an RCS plan's CZ pairs all take the trig-free
     path."""
     from quest_tpu import profiling
     c = random_circuit(16, 20, seed=7, entangler="cz")
@@ -873,13 +874,168 @@ def test_multiphase_counters_name_each_path():
     x = PB.MULTIPHASE_TRIGFREE_MAX
     cache = {}
     with profiling.recording() as rec:
-        for m in (x, x + 1, x + 1):
-            PB.compile_segment_cached(
-                cache, (PB.MultiPhaseStage(("a",) * m),), 16,
-                interpret=True)
-    assert len(cache) == 2
+        for st in (PB.MultiPhaseStage(("a",) * x),
+                   PB.MultiPhaseStage(("a",) * (x + 1)),
+                   PB.MultiPhaseStage(("a",) * (x + 1)),
+                   PB.LookupPhaseStage(("a",) * (x + 1), 2)):
+            PB.compile_segment_cached(cache, (st,), 16, interpret=True)
+    assert len(cache) == 3
     assert rec.counts == {"quest.multiphase_trigfree": 1,
-                          "quest.multiphase_trig": 2}
+                          "quest.multiphase_trig": 2,
+                          "quest.multiphase_lookup": 1}
+
+
+# -- LookupPhaseStage: a wide group's phases from a per-step table where
+#    its terms read few row bits that vary inside the block ---------------
+
+# (stages ahead of the group, block row bits, grid row bits) at 14 qubits
+# in 2^5-row blocks, so that a table of 2^4 rows is still smaller than
+# its block: alone, the block holds row bits 0-4; behind a butterfly on
+# row bit 6, it holds 0-3 and the scattered axis 6
+_LK_N = 14
+_LK_ROWS = 5
+_LK_GEOMETRIES = {
+    "inner": ([], [0, 1, 2, 3, 4], [5, 6]),
+    "scattered": ([PB.MatStage("sc", 2, True, (), (), 6)], [0, 1, 2, 3, 6],
+                  [4, 5]),
+}
+_LK_KERNELS = {}     # stage tuple -> jitted kernel: operands are data
+
+
+def _lk_segment(rng, form, masks, k, geometry, m):
+    """One segment [the geometry's stages, a MultiPhaseStage of m terms]
+    whose terms read `masks` bits: lane bits, k of the block's row bits
+    ('local'), grid row bits ('const'), or all three ('mixed')."""
+    pre, local, const = _LK_GEOMETRIES[geometry]
+    use = [int(b) for b in rng.choice(local, size=k, replace=False)]
+    rows = []
+    for r in range(m):
+        lane, row = [], []
+        if masks in ("lane", "mixed"):
+            lane = list(rng.choice(PB.LANE_QUBITS, size=rng.integers(1, 3),
+                                   replace=False))
+        if masks in ("local", "mixed") and k:
+            row += [use[r % k]] + [b for b in use if rng.random() < 0.3]
+        if masks in ("const", "mixed"):
+            row += list(rng.choice(const, size=rng.integers(1, 3),
+                                   replace=False))
+        rm = sum(1 << int(b) for b in set(row))
+        rows.append([rng.uniform(-np.pi, np.pi), sum(1 << int(q) for q in lane),
+                     rm & 0x7FFF, rm >> 15, 0, 0, 0, 0])
+    ident = np.stack([np.eye(2), np.zeros((2, 2))]).astype(np.float32)
+    return ("segment", pre + [PB.MultiPhaseStage((form,) * m)],
+            [ident] * len(pre) + [np.array(rows, dtype=np.float32)])
+
+
+@pytest.mark.parametrize("geometry", sorted(_LK_GEOMETRIES))
+@pytest.mark.parametrize("masks,k", [("lane", 0), ("const", 0),
+                                     ("local", 1), ("local", 2),
+                                     ("local", 3), ("local", 4),
+                                     ("mixed", 0), ("mixed", 1),
+                                     ("mixed", 2), ("mixed", 3),
+                                     ("mixed", 4)])
+@pytest.mark.parametrize("form", ["a", "p"])
+def test_lookup_stage_matches_oracle(form, masks, k, geometry):
+    """A group above the trig-free constant whose terms read k of the
+    block's row bits becomes a LookupPhaseStage (lookup_plan) and applies
+    the phases its original operand rows define, on a random 14-qubit
+    state in 4 blocks (the grid supplies the other row bits)."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng([k, len(masks), len(geometry), ord(form)])
+    m = PB.MULTIPHASE_TRIGFREE_MAX + 2
+    part = _lk_segment(rng, form, masks, k, geometry, m)
+    operand = part[2][-1]
+    (_, stages, arrays), = PB.lookup_plan([part], _LK_N,
+                                          rows_eff_bits=_LK_ROWS)
+    assert stages[:-1] == part[1][:-1]
+    assert stages[-1] == PB.LookupPhaseStage((form,) * m, k)
+    key = tuple(stages)
+    if key not in _LK_KERNELS:
+        _LK_KERNELS[key] = jax.jit(PB.compile_segment(
+            stages, _LK_N, rows_eff_bits=_LK_ROWS, interpret=True))
+    vec = oracle.random_statevector(_LK_N, rng).astype(np.complex64)
+    amps = np.stack([vec.real, vec.imag]).reshape(2, -1, PB.LANES)
+    out = np.asarray(_LK_KERNELS[key](jnp.asarray(amps), arrays))
+    got = (out[0] + 1j * out[1]).reshape(-1)
+    want = _mp_want(vec.astype(np.complex128), (form,) * m,
+                    operand[:, :4].astype(np.float64))
+    scale = float(np.max(np.abs(want)))
+    np.testing.assert_allclose(got, want, atol=1e-5 * scale, rtol=0)
+
+
+def test_lookup_plan_keeps_other_groups():
+    """lookup_plan leaves a group on its path where the table would not
+    pay: more block row bits than MULTIPHASE_LOOKUP_MAX_BITS (trig), a
+    table as tall as the block (trig: its cos/sin and sums cost what the
+    trig path's do), or few enough terms for the trig-free tree; and a
+    part list without a wide group passes through as it was."""
+    rng = np.random.default_rng(3)
+
+    def reading(k, rows_eff):
+        # k + 1 terms, each reading one of the block's row bits 0..k-1
+        part = ("segment", [PB.MultiPhaseStage(("a",) * (k + 1))],
+                [np.array([[0.3, 1, 1 << (r % k), 0, 0, 0, 0, 0]
+                           for r in range(k + 1)], dtype=np.float32)])
+        return part, _MP_N, rows_eff
+
+    # a block of all 6 row bits: each term reads one of k = max + 1
+    wide = reading(PB.MULTIPHASE_LOOKUP_MAX_BITS + 1,
+                   _MP_N - PB.LANE_QUBITS)
+    # k = max, but the block holds just those k row bits
+    whole = reading(PB.MULTIPHASE_LOOKUP_MAX_BITS,
+                    PB.MULTIPHASE_LOOKUP_MAX_BITS)
+    narrow = (_lk_segment(rng, "a", "local", 1, "inner",
+                          PB.MULTIPHASE_TRIGFREE_MAX), _LK_N, _LK_ROWS)
+    for part, n, rows_eff in (wide, whole, narrow):
+        (got,) = PB.lookup_plan([part], n, rows_eff_bits=rows_eff)
+        assert got[1] == part[1]
+        assert all(a is b for a, b in zip(got[2], part[2]))
+    parts = parts_of(random_circuit(N, depth=4, seed=2))
+    assert PB.lookup_plan(parts, N) == parts
+
+
+def test_lookup_plan_keeps_trig_past_the_vmem_limit(monkeypatch):
+    """A lookup's select tree can price above the trig stage it replaces
+    (2^k + 2 working planes against 5): where that would take the launch
+    past the scoped limit, lookup_plan keeps the group's trig form."""
+    rng = np.random.default_rng(4)
+    part = _lk_segment(rng, "a", "local", PB.MULTIPHASE_LOOKUP_MAX_BITS,
+                       "inner", PB.MULTIPHASE_TRIGFREE_MAX + 2)
+    geo = PB.segment_geometry(part[1], _LK_N, _LK_ROWS)
+    (looked,) = PB.lookup_plan([part], _LK_N, rows_eff_bits=_LK_ROWS)
+    price = PB._vmem_price(looked[1], geo)["total_bytes"]
+    assert price > PB._vmem_price(part[1], geo)["total_bytes"]
+    monkeypatch.setattr(PB, "VMEM_LIMIT_BYTES", price - 1)
+    (kept,) = PB.lookup_plan([part], _LK_N, rows_eff_bits=_LK_ROWS)
+    assert kept[1] == part[1] and kept[2][0] is part[2][0]
+
+
+@pytest.mark.parametrize("n", [10, 11, 12, 14, 15])
+def test_qft_matches_inverse_fft(n):
+    """compiled_fused's QFT is numpy's ifft with norm='ortho'
+    (amplitude j -> sum_k e^{2 pi i jk/N} / sqrt(N)). At 10 and 11
+    qubits one block holds every row bit and the wide groups read all of
+    them, so a table would be as tall as the block: they stay on trig, as
+    at 12, where they read 5; at 14 (one 2^7-row block) the two that read
+    3 and 2 take the lookup, and at 15 three, beside a scattered axis."""
+    import jax.numpy as jnp
+    from quest_tpu import profiling
+    rng = np.random.default_rng(n)
+    vec = oracle.random_statevector(n, rng)
+    with profiling.recording() as rec:
+        fn = qft_circuit(n).compiled_fused(n, False, donate=False,
+                                           interpret=True)
+    amps = np.stack([vec.real, vec.imag]).astype(np.float32)
+    out = np.asarray(fn(jnp.asarray(amps.reshape(2, -1, PB.LANES))))
+    got = out.reshape(2, -1)
+    np.testing.assert_allclose(got[0] + 1j * got[1],
+                               np.fft.ifft(vec, norm="ortho"), atol=5e-6,
+                               rtol=0)
+    lookups = rec.counts.get("quest.multiphase_lookup", 0)
+    trig = rec.counts.get("quest.multiphase_trig", 0)
+    assert (lookups, trig) == {10: (0, 2), 11: (0, 3), 12: (0, 4),
+                               14: (2, 5), 15: (3, 5)}[n]
 
 
 def test_qft_cut_by_vmem_budget_matches_oracle():

@@ -121,6 +121,32 @@ def test_qft30_sweeps_price_within_limit():
     assert sum(len(p[1]) for p in parts) == 98
 
 
+# QFT-30's wide groups on the block-local lookup, per sweep: the row bits
+# each reads inside its sweep's block (docs/KERNELS.md)
+QFT30_LOOKUP_BITS = [[3, 3, 2], [3, 3], [3, 1, 1, 1, 2, 2, 2, 2, 2],
+                     [1, 0, 0, 0, 0, 1], [], [4, 4, 4, 4, 1, 2], [], []]
+
+
+def test_qft30_lookup_groups():
+    """lookup_plan moves 26 of QFT-30's 48 trig groups to the lookup and
+    changes nothing else of the plan: the sweeps, their stage counts and
+    block geometry; each launch still prices within the scoped limit."""
+    parts = _swept(qft_circuit(30), 30)
+    looked = PB.lookup_plan(parts, 30)
+    assert len(looked) == QFT30_GOLDEN_SWEEPS
+    for p, q in zip(parts, looked):
+        assert len(p[1]) == len(q[1])
+        assert PB.segment_geometry(p[1], 30) == PB.segment_geometry(q[1], 30)
+        assert PB.sweep_vmem_bytes(q[1], q[2], 30)["total_bytes"] <= \
+            PB.VMEM_LIMIT_BYTES
+    assert [[st.k for st in q[1] if isinstance(st, PB.LookupPhaseStage)]
+            for q in looked] == QFT30_LOOKUP_BITS
+    trig = sum(isinstance(st, PB.MultiPhaseStage)
+               and not PB.multiphase_trigfree(st) for q in looked
+               for st in q[1])
+    assert (sum(map(len, QFT30_LOOKUP_BITS)), trig) == (26, 22)
+
+
 def test_adjacent_butterflies_across_applications_priced():
     """Two applications of a lone high-qubit gate would merge into two
     unmasked butterflies on one bit, which Mosaic holds in 52 planes (48
@@ -201,7 +227,7 @@ def test_benchmark_cell_plans_unchanged_by_vmem_price(cell):
     import hashlib
     import re
     plan, kinds, digest = CELL_PLANS[cell]
-    parts = plan()
+    parts = PB.lookup_plan(plan(), 30)     # as the engine compiles them
     names = [PB.kernel_name(p[1], PB.segment_geometry(p[1], 30))
              for p in parts]
     assert [re.sub(r"^quest_seg_|_[0-9a-f]{8}$", "", x)
@@ -224,7 +250,8 @@ def test_vmem_cut_counted_in_plan_span():
     peak = max(PB.sweep_vmem_bytes(p[1], p[2], 30)["total_bytes"]
                for p in _swept(c, 30))
     assert rec.counts["quest.sweep_vmem_peak_mib"] == peak / (1 << 20)
-    assert rec.counts["quest.multiphase_trig"] == 48
+    assert rec.counts["quest.multiphase_trig"] == 22
+    assert rec.counts["quest.multiphase_lookup"] == 26
     with profiling.recording() as rec:
         random_circuit(12, 4, seed=1).compiled_fused(12, False)
     assert rec.counts["quest.sweep_vmem_cut"] == 0
@@ -509,6 +536,36 @@ def test_sweep_fused_matches_oracle_sharded(tmpl, inst, rdt):
     scale = max(1.0, float(np.max(np.abs(want))))
     np.testing.assert_allclose(got[0] + 1j * got[1], want,
                                atol=eps * scale, rtol=0)
+
+
+def test_sharded_fused_lookup_matches_oracle():
+    """The sharded fused engine localizes each shard's sweeps too: QFT's
+    phase ladder on the 14 local qubits of a 15-qubit register on a
+    2-device mesh (2^7-row blocks, so a table is shorter than its block)
+    takes the lookup for its wide groups and matches the oracle."""
+    from quest_tpu import profiling
+    from quest_tpu.parallel.mesh import make_amp_mesh
+
+    n = 15
+    c = Circuit(n)
+    c.h(n - 1)
+    for q in reversed(range(n - 1)):
+        c.h(q)
+        for j in range(q):
+            c.cphase(np.pi / (1 << (q - j)), j, q)
+    c.cz(0, n - 1)
+    rng = np.random.default_rng(11)
+    amps = rng.standard_normal((2, 1 << n)).astype(np.float32)
+    with profiling.recording() as rec:
+        fn = c.compiled_sharded_fused(n, density=False,
+                                      mesh=make_amp_mesh(2), donate=False,
+                                      interpret=True)
+        got = np.asarray(fn(jnp.asarray(amps))).reshape(2, -1)
+    assert rec.counts["quest.multiphase_lookup"] == 2
+    want = _oracle_apply_ops(_oracle_vec(amps), n, c.ops)
+    scale = max(1.0, float(np.max(np.abs(want))))
+    np.testing.assert_allclose(got[0] + 1j * got[1], want,
+                               atol=EPS_F32 * scale, rtol=0)
 
 
 def test_compiled_fused_cross_iteration_end_to_end():
